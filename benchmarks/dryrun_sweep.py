@@ -49,6 +49,7 @@ def run_one(arch: str, shape: str, mesh: str, force: bool) -> dict:
     ]
     t0 = time.time()
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"  # placeholder host devices, never the chip
     proc = subprocess.run(
         cmd, capture_output=True, text=True, env=env, timeout=1800
     )

@@ -543,6 +543,9 @@ for faulted in (False, True):
 print('DONE')
 """
     env = dict(os.environ)
+    # the child's 8 fake host devices are CPU devices; on a chip host it
+    # must not reach for the TPU this parent already holds
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = os.path.join(
         os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src"

@@ -53,6 +53,7 @@ from repro.core.mixers import (
     NeighborMixer,
     PpermuteMixer,
 )
+from repro.utils import compat
 
 
 # ---------------------------------------------------------------------------
@@ -75,7 +76,7 @@ class DCELMRule:
 
     def __call__(self, x, lap, aux, gamma):
         V, C = self.num_nodes, self.C
-        update = jnp.einsum("vlk,vkm->vlm", aux, lap)
+        update = jnp.einsum("vlk,vkm->vlm", aux, lap, precision="highest")
         return x + (gamma / (V * C)) * update
 
 
@@ -279,7 +280,17 @@ class ConsensusEngine:
                     omega=stats_lib.omega_from_moments(P_, C, V), Q=Q_
                 )
 
-            states = jax.vmap(node)(X_nodes, T_nodes)
+            per_node = jax.vmap(node)
+            mesh = getattr(self.mixer, "mesh", None)
+            if mesh is not None:
+                # one node per device: each shard runs its own stats
+                # pass where its data already lives
+                spec = self.mixer.node_pspec()
+                per_node = jax.jit(compat.shard_map(
+                    per_node, mesh, in_specs=(spec, spec), out_specs=spec,
+                    check_vma=False,
+                ))
+            states = per_node(X_nodes, T_nodes)
         else:
             states = jax.vmap(lambda h, t: online.init_state(h, t, C, V))(
                 H_nodes, T_nodes

@@ -80,7 +80,8 @@ class RandomFeatureMap:
     def __call__(self, x: jax.Array) -> jax.Array:
         """x: (..., D) -> H: (..., L)."""
         g = ACTIVATIONS[self.activation]
-        return g(x @ self.weights + self.bias)
+        z = jnp.matmul(x, self.weights, precision="highest")
+        return g(z + self.bias)
 
 
 def rbf_squared_dists(
@@ -97,7 +98,7 @@ def rbf_squared_dists(
     if centers_sq is None:
         centers_sq = jnp.sum(jnp.square(centers), axis=-1)
     x_sq = jnp.sum(jnp.square(x), axis=-1, keepdims=True)
-    cross = x @ centers.T
+    cross = jnp.matmul(x, centers.T, precision="highest")
     return jnp.maximum(x_sq - 2.0 * cross + centers_sq, 0.0)
 
 

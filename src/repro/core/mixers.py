@@ -166,7 +166,8 @@ class DenseMixer:
             dt = _mix_dtype(payload.dtype)
             p = payload.astype(dt)
             a = adj.astype(dt)
-            lap = a @ p - deg.astype(dt)[:, None] * p
+            a_p = jnp.matmul(a, p, precision="highest")
+            lap = a_p - deg.astype(dt)[:, None] * p
             return lap.astype(v.dtype).reshape(v.shape)
 
         return jax.tree.map(leaf, x)

@@ -46,7 +46,7 @@ class OnlineNodeState:
 
     @property
     def beta(self) -> jax.Array:
-        return self.omega @ self.Q
+        return jnp.matmul(self.omega, self.Q, precision="highest")
 
 
 def init_state(H: jax.Array, T: jax.Array, C: float, V: int) -> OnlineNodeState:
@@ -60,17 +60,17 @@ def init_state(H: jax.Array, T: jax.Array, C: float, V: int) -> OnlineNodeState:
 def woodbury_add(omega: jax.Array, dH: jax.Array) -> jax.Array:
     """Rank-dN downdate of the inverse after ADDING rows dH (eq. 27)."""
     dN = dH.shape[0]
-    S = jnp.eye(dN, dtype=omega.dtype) + dH @ omega @ dH.T
-    K = omega @ dH.T
-    return omega - K @ jnp.linalg.solve(S, K.T)
+    K = jnp.matmul(omega, dH.T, precision="highest")
+    S = jnp.eye(dN, dtype=omega.dtype) + jnp.matmul(dH, K, precision="highest")
+    return omega - jnp.matmul(K, jnp.linalg.solve(S, K.T), precision="highest")
 
 
 def woodbury_remove(omega: jax.Array, dH: jax.Array) -> jax.Array:
     """Rank-dN update of the inverse after REMOVING rows dH (eq. 26)."""
     dN = dH.shape[0]
-    S = jnp.eye(dN, dtype=omega.dtype) - dH @ omega @ dH.T
-    K = omega @ dH.T
-    return omega + K @ jnp.linalg.solve(S, K.T)
+    K = jnp.matmul(omega, dH.T, precision="highest")
+    S = jnp.eye(dN, dtype=omega.dtype) - jnp.matmul(dH, K, precision="highest")
+    return omega + jnp.matmul(K, jnp.linalg.solve(S, K.T), precision="highest")
 
 
 @jax.jit
@@ -78,7 +78,7 @@ def remove_chunk(state: OnlineNodeState, dH: jax.Array, dT: jax.Array):
     """Algorithm 2, steps 5-8."""
     return OnlineNodeState(
         omega=woodbury_remove(state.omega, dH),
-        Q=state.Q - dH.T @ dT,
+        Q=state.Q - jnp.matmul(dH.T, dT, precision="highest"),
     )
 
 
@@ -87,7 +87,7 @@ def add_chunk(state: OnlineNodeState, dH: jax.Array, dT: jax.Array):
     """Algorithm 2, steps 9-12."""
     return OnlineNodeState(
         omega=woodbury_add(state.omega, dH),
-        Q=state.Q + dH.T @ dT,
+        Q=state.Q + jnp.matmul(dH.T, dT, precision="highest"),
     )
 
 
@@ -194,7 +194,9 @@ batched_rescale_num_nodes = jax.jit(
 
 def reseed_betas(states: OnlineNodeState) -> jax.Array:
     """Stacked beta_i = Omega_i Q_i after an online update (step 13)."""
-    return jnp.einsum("vlk,vkm->vlm", states.omega, states.Q)
+    return jnp.einsum(
+        "vlk,vkm->vlm", states.omega, states.Q, precision="highest"
+    )
 
 
 @functools.partial(jax.jit, static_argnames=("C", "V"))

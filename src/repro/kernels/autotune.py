@@ -313,10 +313,10 @@ def working_set_bytes(point: TunePoint, cfg: dict) -> float:
         # two Z tiles + two H tiles + T tile + f32 P/Q blocks
         return s * (4 * bn * bl + bn * M) + 4.0 * (bl * bl + bl * M)
     if point.op == "stacked":
-        # X tile + W block + H tile + (T, bl, M) beta block + gathered
-        # (bn, bl, M) tiles + f32 out block
+        # X tile + W block + H scratch + one tenant's (bl, M) beta tile
+        # (double-buffered) + f32 out block
         return s * (bn * D + D * bl + bn * bl) + 4.0 * (
-            T * bl * M + bn * bl * M + bn * M
+            2 * bl * M + bn * M
         )
     # predict: X tile + W block + H tile + beta block + f32 out block
     return s * (bn * D + D * bl + bn * bl + bl * M) + 4.0 * bn * M
@@ -377,11 +377,11 @@ def hbm_bytes(point: TunePoint, cfg: dict) -> float:
         zpasses = jblocks * (jblocks + 1) / 2
         return s * 2.0 * N * bl * zpasses + 4.0 * (L * L + L * M)
     # predict/stacked: X re-streams once per j (L) block; the stacked
-    # path additionally re-reads the (T, bl, M) beta block per grid
-    # step and gathers (bn, bl, M) per-row tiles
+    # path additionally reads each row block's distinct tenants' betas
+    # (at most min(T, bn) of them)
     base = s * N * D * jblocks + s * D * L * math.ceil(N / bn) + s * N * M
     if point.op == "stacked":
-        base += 4.0 * (T * L * M * math.ceil(N / bn) + N * L * M)
+        base += 4.0 * min(T, bn) * L * M * math.ceil(N / bn)
     return base
 
 

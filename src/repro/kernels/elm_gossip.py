@@ -41,105 +41,94 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _rup(x: int, m: int) -> int:
     return x + (-x) % m
 
 
-def _lap_tile(src, idx, wts, deg, lo, block_v, d_max, bf16):
-    """f32 Laplacian for the node block starting at ``lo``.
+_SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)  # whole array, scalar reads
 
-    src: (Vp, Mp, Lp) gather source (state or encoded payload);
-    idx/wts: (block_v, d_pad); deg: (block_v,). The gathered tiles are
-    VMEM values — this accumulation is the fusion.
+
+def _node_update(
+    src_ref, beta_row, omega_row, idx_ref, w_ref, deg_ref, scale, *,
+    node, row, d_max, bf16,
+):
+    """beta_i + scale * (sum_s w[i,s] src[idx[i,s]] - deg_i src_i) @ Omega_i^T.
+
+    ``node`` indexes the gather source and ``row`` the flattened
+    (snapshot, node) neighbor-list row. The gathered (Mp, Lp) tiles are
+    ref-level reads at a scalar index from SMEM; the f32 Laplacian never
+    leaves VMEM. upd[m, l] = sum_k lap[m, k] * Omega[l, k] contracts
+    both lane dims on the MXU.
     """
-    if bf16:
-        src = src.astype(jnp.bfloat16)
-    p_tile = jax.lax.dynamic_slice_in_dim(src, lo, block_v, axis=0)
-    lap0 = -deg[:, None, None] * p_tile.astype(jnp.float32)
+
+    def payload(j):
+        p = src_ref[j]
+        if bf16:
+            p = p.astype(jnp.bfloat16)
+        return p.astype(jnp.float32)
 
     def acc(s, lap):
-        col = jax.lax.dynamic_index_in_dim(idx, s, axis=1, keepdims=False)
-        ws = jax.lax.dynamic_index_in_dim(wts, s, axis=1, keepdims=False)
-        g = jnp.take(src, col, axis=0).astype(jnp.float32)
-        return lap + ws[:, None, None] * g
+        slot = row * d_max + s
+        return lap + w_ref[slot] * payload(idx_ref[slot])
 
-    return jax.lax.fori_loop(0, d_max, acc, lap0)
-
-
-def _apply_omega(beta_tile, omega, lap, scale):
-    """beta + scale * Omega @ lap in the (M, L) lane layout.
-
-    upd[v, m, l] = sum_k omega[v, l, k] * lap[v, m, k] — contracting
-    both lane (k) dims on the MXU with f32 accumulation.
-    """
+    lap = jax.lax.fori_loop(0, d_max, acc, -deg_ref[row] * payload(node))
     upd = jax.lax.dot_general(
-        lap,
-        omega,
-        dimension_numbers=(((2,), (2,)), ((0,), (0,))),
+        lap, omega_row,
+        dimension_numbers=(((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,  # f32 state and Omega
         preferred_element_type=jnp.float32,
     )
-    return beta_tile + scale * upd
+    return beta_row + scale * upd
 
 
 def _round_kernel(
-    scale_ref, beta_ref, omega_ref, idx_ref, w_ref, deg_ref, out_ref,
-    *, block_v, d_max, bf16,
+    idx_ref, w_ref, deg_ref, scale_ref, src_ref, beta_ref, omega_ref,
+    out_ref, *, block_v, d_max, bf16,
 ):
-    i = pl.program_id(0)
-    beta_full = beta_ref[...]
-    lap = _lap_tile(
-        beta_full, idx_ref[...], w_ref[...].astype(jnp.float32),
-        deg_ref[...][:, 0].astype(jnp.float32), i * block_v, block_v,
-        d_max, bf16,
-    )
-    beta_tile = jax.lax.dynamic_slice_in_dim(
-        beta_full, i * block_v, block_v, axis=0
-    )
-    out_ref[...] = _apply_omega(
-        beta_tile, omega_ref[...], lap, scale_ref[0, 0]
-    )
+    """One round for a block of ``block_v`` nodes. ``src_ref`` is the
+    whole gather source (state or encoded payload) resident in VMEM;
+    ``beta_ref`` is this block's state rows."""
+    base = pl.program_id(0) * block_v
 
+    def node(v, carry):
+        out_ref[v] = _node_update(
+            src_ref, beta_ref[v], omega_ref[v], idx_ref, w_ref, deg_ref,
+            scale_ref[0], node=base + v, row=base + v, d_max=d_max,
+            bf16=bf16,
+        )
+        return carry
 
-def _round_kernel_payload(
-    scale_ref, beta_ref, pay_ref, omega_ref, idx_ref, w_ref, deg_ref,
-    out_ref, *, block_v, d_max,
-):
-    i = pl.program_id(0)
-    lap = _lap_tile(
-        pay_ref[...], idx_ref[...], w_ref[...].astype(jnp.float32),
-        deg_ref[...][:, 0].astype(jnp.float32), i * block_v, block_v,
-        d_max, bf16=False,
-    )
-    beta_tile = jax.lax.dynamic_slice_in_dim(
-        beta_ref[...], i * block_v, block_v, axis=0
-    )
-    out_ref[...] = _apply_omega(
-        beta_tile, omega_ref[...], lap, scale_ref[0, 0]
-    )
+    jax.lax.fori_loop(0, block_v, node, 0)
 
 
 def _multiround_kernel(
-    scale_ref, beta_ref, omega_ref, idx_ref, w_ref, deg_ref, out_ref,
-    *, d_max, num_snapshots, num_rounds, bf16,
+    idx_ref, w_ref, deg_ref, scale_ref, beta_ref, omega_ref, out_ref,
+    next_ref, *, num_nodes, d_max, num_snapshots, num_rounds, bf16,
 ):
-    omega = omega_ref[...]
-    idx_all = idx_ref[...]
-    w_all = w_ref[...].astype(jnp.float32)
-    deg_all = deg_ref[...].astype(jnp.float32)
-    scale = scale_ref[0, 0]
-    V = omega.shape[0]
+    """All rounds with the state resident: round k reads ``out_ref``,
+    writes every node's update to ``next_ref``, then copies it back
+    (every node must see the same round-k state)."""
+    out_ref[...] = beta_ref[...]
 
-    def round_fn(k, b):
-        s = jax.lax.rem(k, num_snapshots)
-        idx = jax.lax.dynamic_index_in_dim(idx_all, s, 0, keepdims=False)
-        wts = jax.lax.dynamic_index_in_dim(w_all, s, 0, keepdims=False)
-        deg = jax.lax.dynamic_index_in_dim(deg_all, s, 0, keepdims=False)
-        lap = _lap_tile(b, idx, wts, deg, 0, V, d_max, bf16)
-        return _apply_omega(b, omega, lap, scale)
+    def round_fn(k, carry):
+        first_row = jax.lax.rem(k, num_snapshots) * num_nodes
 
-    out_ref[...] = jax.lax.fori_loop(0, num_rounds, round_fn, beta_ref[...])
+        def node(v, c):
+            next_ref[v] = _node_update(
+                out_ref, out_ref[v], omega_ref[v], idx_ref, w_ref,
+                deg_ref, scale_ref[0], node=v, row=first_row + v,
+                d_max=d_max, bf16=bf16,
+            )
+            return c
+
+        jax.lax.fori_loop(0, num_nodes, node, 0)
+        out_ref[...] = next_ref[...]
+        return carry
+
+    jax.lax.fori_loop(0, num_rounds, round_fn, 0)
 
 
 # ---------------------------------------------------------------------------
@@ -148,26 +137,29 @@ def _multiround_kernel(
 
 
 def _prep(betas, omegas, idx, w, deg, block_v):
-    """(V, L, M) -> padded kernel operands in the (V, M, L) layout."""
+    """(V, L, M) -> padded kernel operands in the (V, M, L) layout.
+
+    The neighbor lists go to SMEM flattened per snapshot: idx/w become
+    (S, Vp * d_max) and deg (S, Vp), so row ``v`` of a snapshot starts
+    at ``v * d_max``.
+    """
     V, L, M = betas.shape
-    bv = min(max(int(block_v), 1), _rup(V, 1))
+    S, _, d_max = idx.shape
+    bv = max(1, min(int(block_v), V))
     Vp = _rup(V, bv)
     Lp = _rup(L, 128)
     Mp = _rup(M, 8)
-    dp = _rup(idx.shape[-1], 128)
     bt = jnp.transpose(betas, (0, 2, 1)).astype(jnp.float32)
     bt = jnp.pad(bt, ((0, Vp - V), (0, Mp - M), (0, Lp - L)))
     om = jnp.pad(
         omegas.astype(jnp.float32),
         ((0, Vp - V), (0, Lp - L), (0, Lp - L)),
     )
-    ip = jnp.pad(idx, ((0, 0), (0, Vp - V), (0, dp - idx.shape[-1])))
-    wp = jnp.pad(
-        w.astype(jnp.float32),
-        ((0, 0), (0, Vp - V), (0, dp - w.shape[-1])),
-    )
+    pad_v = ((0, 0), (0, Vp - V), (0, 0))
+    ip = jnp.pad(idx.astype(jnp.int32), pad_v).reshape(S, Vp * d_max)
+    wp = jnp.pad(w.astype(jnp.float32), pad_v).reshape(S, Vp * d_max)
     dg = jnp.pad(deg.astype(jnp.float32), ((0, 0), (0, Vp - V)))
-    return bt, om, ip, wp, dg, (Vp, Lp, Mp, dp, bv)
+    return bt, om, ip, wp, dg, (Vp, Lp, Mp, bv)
 
 
 def _unpack(out, V, L, M, dtype):
@@ -177,6 +169,34 @@ def _unpack(out, V, L, M, dtype):
 def _snapshot(arr, k):
     S = arr.shape[0]
     return arr[0] if S == 1 else jnp.take(arr, jnp.mod(k, S), axis=0)
+
+
+def round_vmem_bytes(V, L, M, block_v, *, payload=False) -> int:
+    """VMEM the per-round arm keeps: the whole gather source (single
+    buffered — its block never moves), and double-buffered per-block
+    state, Omega and output tiles."""
+    Vp, Lp, Mp = _rup(V, block_v), _rup(L, 128), _rup(M, 8)
+    state = 4 * Vp * Mp * Lp
+    tiles = 2 * 4 * block_v * (2 * Mp * Lp + Lp * Lp)
+    return state * (2 if payload else 1) + tiles
+
+
+def fit_block_v(V, L, M, block_v, budget, *, payload=False) -> int:
+    """The largest node block <= ``block_v`` whose resident set fits
+    ``budget`` (1 when none does: the Omega tile is then the floor)."""
+    bv = max(1, min(int(block_v), V))
+    while bv > 1 and round_vmem_bytes(V, L, M, bv, payload=payload) > budget:
+        bv //= 2
+    return bv
+
+
+def _vmem_params(nbytes: int):
+    """Raise the scoped VMEM limit only when the resident set needs it
+    (the default scoped limit is 16 MiB; v5e has 128 MiB per core)."""
+    limit = nbytes + 4 * 2**20
+    if limit <= 16 * 2**20:
+        return None
+    return pltpu.CompilerParams(vmem_limit_bytes=int(limit))
 
 
 # ---------------------------------------------------------------------------
@@ -201,39 +221,40 @@ def elm_gossip_pallas(
             "an explicit payload= is re-encoded outside the kernel every "
             f"round, so it implies num_rounds=1 (got {num_rounds})"
         )
-    bf16 = compress == "bf16"
     V, L, M = betas.shape
     d_max = idx.shape[-1]
-    bt, om, ip, wp, dg, (Vp, Lp, Mp, dp, bv) = _prep(
+    bt, om, ip, wp, dg, (Vp, Lp, Mp, bv) = _prep(
         betas, omegas, idx, w, deg, block_v
     )
-    scale = jnp.asarray(scale, jnp.float32).reshape(1, 1)
-    grid = (Vp // bv,)
-    full = pl.BlockSpec((Vp, Mp, Lp), lambda i: (0, 0, 0))
-    tiled3 = pl.BlockSpec((bv, Mp, Lp), lambda i: (i, 0, 0))
-    omega_spec = pl.BlockSpec((bv, Lp, Lp), lambda i: (i, 0, 0))
-    list_spec = pl.BlockSpec((bv, dp), lambda i: (i, 0))
-    deg_spec = pl.BlockSpec((bv, 1), lambda i: (i, 0))
-    scale_spec = pl.BlockSpec((1, 1), lambda i: (0, 0))
-    out_shape = jax.ShapeDtypeStruct((Vp, Mp, Lp), jnp.float32)
+    scale = jnp.asarray(scale, jnp.float32).reshape(1)
+    kernel = functools.partial(
+        _round_kernel, block_v=bv, d_max=d_max, bf16=compress == "bf16"
+    )
+    call = pl.pallas_call(
+        kernel,
+        grid=(Vp // bv,),
+        in_specs=[
+            _SMEM, _SMEM, _SMEM, _SMEM,
+            pl.BlockSpec(
+                (Vp, Mp, Lp), lambda i: (0, 0, 0),
+                pipeline_mode=pl.Buffered(1),
+            ),
+            pl.BlockSpec((bv, Mp, Lp), lambda i: (i, 0, 0)),
+            pl.BlockSpec((bv, Lp, Lp), lambda i: (i, 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((bv, Mp, Lp), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((Vp, Mp, Lp), jnp.float32),
+        compiler_params=_vmem_params(
+            round_vmem_bytes(V, L, M, bv, payload=payload is not None)
+        ),
+        interpret=interpret,
+    )
 
     if payload is None:
-        kernel = functools.partial(
-            _round_kernel, block_v=bv, d_max=d_max, bf16=bf16
-        )
-        in_specs = [scale_spec, full, omega_spec, list_spec, list_spec,
-                    deg_spec]
 
         def one_round(b, k):
-            out = pl.pallas_call(
-                kernel, grid=grid, in_specs=in_specs,
-                out_specs=tiled3, out_shape=out_shape,
-                interpret=interpret,
-            )(
-                scale, b, om, _snapshot(ip, k), _snapshot(wp, k),
-                _snapshot(dg, k)[:, None],
-            )
-            return out, None
+            lists = (_snapshot(ip, k), _snapshot(wp, k), _snapshot(dg, k))
+            return call(*lists, scale, b, b, om), None
 
         if num_rounds == 1:
             out = one_round(bt, 0)[0]
@@ -243,15 +264,7 @@ def elm_gossip_pallas(
 
     pt = jnp.transpose(payload, (0, 2, 1)).astype(jnp.float32)
     pt = jnp.pad(pt, ((0, Vp - V), (0, Mp - M), (0, Lp - L)))
-    kernel = functools.partial(
-        _round_kernel_payload, block_v=bv, d_max=d_max
-    )
-    out = pl.pallas_call(
-        kernel, grid=grid,
-        in_specs=[scale_spec, full, full, omega_spec, list_spec,
-                  list_spec, deg_spec],
-        out_specs=tiled3, out_shape=out_shape, interpret=interpret,
-    )(scale, bt, pt, om, ip[0], wp[0], dg[0][:, None])
+    out = call(ip[0], wp[0], dg[0], scale, pt, bt, om)
     return _unpack(out, V, L, M, betas.dtype)
 
 
@@ -263,39 +276,41 @@ def elm_gossip_pallas_multiround(
 
     Small-state arm — gate callers on ``multiround_vmem_bytes``. The
     topology snapshots (time-varying bases, FaultyMixer masked periods)
-    ride along in VMEM and round k picks snapshot k % S in-kernel.
+    ride along in SMEM and round k picks snapshot k % S in-kernel.
     """
-    bf16 = compress == "bf16"
     V, L, M = betas.shape
     S, _, d_max = idx.shape
-    bt, om, ip, wp, dg, (Vp, Lp, Mp, dp, _) = _prep(
+    bt, om, ip, wp, dg, (Vp, Lp, Mp, _) = _prep(
         betas, omegas, idx, w, deg, block_v=V
     )
-    scale = jnp.asarray(scale, jnp.float32).reshape(1, 1)
+    scale = jnp.asarray(scale, jnp.float32).reshape(1)
 
     def whole(*dims):
         return pl.BlockSpec(dims, lambda: (0,) * len(dims))
 
     kernel = functools.partial(
-        _multiround_kernel, d_max=d_max, num_snapshots=S,
-        num_rounds=num_rounds, bf16=bf16,
+        _multiround_kernel, num_nodes=Vp, d_max=d_max, num_snapshots=S,
+        num_rounds=num_rounds, bf16=compress == "bf16",
     )
     out = pl.pallas_call(
         kernel,
         grid=(),
         in_specs=[
-            whole(1, 1), whole(Vp, Mp, Lp), whole(Vp, Lp, Lp),
-            whole(S, Vp, dp), whole(S, Vp, dp), whole(S, Vp),
+            _SMEM, _SMEM, _SMEM, _SMEM,
+            whole(Vp, Mp, Lp), whole(Vp, Lp, Lp),
         ],
         out_specs=whole(Vp, Mp, Lp),
         out_shape=jax.ShapeDtypeStruct((Vp, Mp, Lp), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((Vp, Mp, Lp), jnp.float32)],
+        compiler_params=_vmem_params(multiround_vmem_bytes(V, L, M, S, d_max)),
         interpret=interpret,
-    )(scale, bt, om, ip, wp, dg)
+    )(ip.reshape(-1), wp.reshape(-1), dg.reshape(-1), scale, bt, om)
     return _unpack(out, V, L, M, betas.dtype)
 
 
 def multiround_vmem_bytes(V, L, M, S, d_max) -> int:
-    """Resident bytes of the multi-round arm (everything in VMEM)."""
-    Vp, Lp, Mp, dp = V, _rup(L, 128), _rup(M, 8), _rup(d_max, 128)
-    state = 4 * Vp * Mp * Lp  # beta in + out + lap accumulator
-    return 3 * state + 4 * Vp * Lp * Lp + S * Vp * (4 * dp + 4 * dp + 4)
+    """Resident VMEM bytes of the multi-round arm: state in, out and
+    next, plus every Omega (the neighbor lists live in SMEM)."""
+    del S, d_max
+    Lp, Mp = _rup(L, 128), _rup(M, 8)
+    return 3 * 4 * V * Mp * Lp + 4 * V * Lp * Lp

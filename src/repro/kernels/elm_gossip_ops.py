@@ -132,6 +132,7 @@ def fused_gossip_rounds(
         from repro.kernels.elm_gossip import (
             elm_gossip_pallas,
             elm_gossip_pallas_multiround,
+            fit_block_v,
             multiround_vmem_bytes,
         )
 
@@ -142,6 +143,7 @@ def fused_gossip_rounds(
         bv = cfg.get("block_n") or autotune.DEFAULTS[
             ("gossip", "pallas")
         ]["block_n"]
+        bv = fit_block_v(V, L, M, bv, autotune.VMEM_BUDGET)
         interp = (not _on_tpu()) if interpret is None else interpret
         if (
             multiround_vmem_bytes(V, L, M, S, d_max)
@@ -196,7 +198,7 @@ def fused_gossip_round(
     if betas.dtype != jnp.float32 or payload.dtype != jnp.float32:
         use = False
     if use:
-        from repro.kernels.elm_gossip import elm_gossip_pallas
+        from repro.kernels.elm_gossip import elm_gossip_pallas, fit_block_v
 
         cfg = _resolve(
             {"block_n": block_v}, tuning,
@@ -205,6 +207,7 @@ def fused_gossip_round(
         bv = cfg.get("block_n") or autotune.DEFAULTS[
             ("gossip", "pallas")
         ]["block_n"]
+        bv = fit_block_v(V, L, M, bv, autotune.VMEM_BUDGET, payload=True)
         interp = (not _on_tpu()) if interpret is None else interpret
         return elm_gossip_pallas(
             betas, omegas, idx_k[None], w_k[None], deg_k[None], scale,

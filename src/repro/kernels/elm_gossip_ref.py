@@ -142,7 +142,7 @@ def neighbor_laplacian(payload, idx_k, w_k, deg_k, *, chunk=None):
     lap0 = -deg_k.astype(dt)[:, None] * pf
     if steps == 1:
         g = jnp.take(pf, idx_k, axis=0)  # (V, c, F)
-        lap = lap0 + jnp.einsum("vc,vcf->vf", wc, g)
+        lap = lap0 + jnp.einsum("vc,vcf->vf", wc, g, precision="highest")
     else:
         ic = idx_k.reshape(V, steps, c).transpose(1, 0, 2)  # (steps, V, c)
         ws = wc.reshape(V, steps, c).transpose(1, 0, 2)
@@ -150,7 +150,9 @@ def neighbor_laplacian(payload, idx_k, w_k, deg_k, *, chunk=None):
         def acc(lap, sc):
             sl, sw = sc
             g = jnp.take(pf, sl, axis=0)  # (V, c, F)
-            return lap + jnp.einsum("vc,vcf->vf", sw, g), None
+            return lap + jnp.einsum(
+                "vc,vcf->vf", sw, g, precision="highest"
+            ), None
 
         lap, _ = lax.scan(acc, lap0, (ic, ws))
     return lap.reshape((V,) + trail)
@@ -168,7 +170,7 @@ def gossip_round_reference(
     """
     p = _payload(betas, compress)
     lap = neighbor_laplacian(p, idx_k, w_k, deg_k).astype(betas.dtype)
-    upd = jnp.einsum("vlk,vkm->vlm", omegas, lap)
+    upd = jnp.einsum("vlk,vkm->vlm", omegas, lap, precision="highest")
     return (betas + scale * upd).astype(betas.dtype)
 
 
@@ -186,7 +188,7 @@ def gossip_round_payload(
     lap = neighbor_laplacian(
         payload, idx_k, w_k, deg_k, chunk=chunk
     ).astype(betas.dtype)
-    upd = jnp.einsum("vlk,vkm->vlm", omegas, lap)
+    upd = jnp.einsum("vlk,vkm->vlm", omegas, lap, precision="highest")
     return (betas + scale * upd).astype(betas.dtype)
 
 
@@ -249,8 +251,11 @@ def dense_gossip_rounds(
         p = p.astype(dt)
         a_k = _snapshot(adj, k).astype(dt)
         d_k = _snapshot(deg, k).astype(dt)
-        lap = (a_k @ p - d_k[:, None] * p).astype(b.dtype)
-        upd = jnp.einsum("vlk,vkm->vlm", omegas, lap.reshape(V, L, M))
+        a_p = jnp.matmul(a_k, p, precision="highest")
+        lap = (a_p - d_k[:, None] * p).astype(b.dtype)
+        upd = jnp.einsum(
+            "vlk,vkm->vlm", omegas, lap.reshape(V, L, M), precision="highest"
+        )
         return (b + scale * upd).astype(b.dtype), None
 
     final, _ = lax.scan(round_fn, betas, jnp.arange(num_rounds))

@@ -44,19 +44,18 @@ g(0)-valued padded hidden columns contribute nothing.
 Stacked multi-tenant path (``elm_predict_stacked_pallas``): a
 micro-batch mixing many tenants carries per-row ids into a stacked
 (T, L, M) beta tensor. The shared hidden tile g(XW+b) is computed
-ONCE per (bn, bl) grid step — exactly as above — and contracts against
-the per-row gathered beta tiles
+ONCE per (bn, bl) block and kept in VMEM scratch while an inner grid
+axis walks the distinct tenants of the row block:
 
-    Y[i] += batched_dot(H_tile, betas[tid[i], l_blk])    (bn, M)
+    Y[i] += where(tid == t, H_tile, 0) @ betas[t, l_blk]    (bn, M)
 
 so serving T tenants costs one launch, not T: the feature work is
-shared, only the readout gather is per-tenant (decentralized
-multi-task ELM, arXiv 1904.11366). The beta block is (T, bl, M) — the
-T axis rides whole while L is blocked — and the row gather is a
-jnp.take inside the kernel (VMEM gather; for tenant counts whose
-stacked block outgrows VMEM, shrink ``block_l`` — the autotuner sweeps
-it). Masked padded rows carry tenant id 0; their hidden rows are
-exact zeros so the gathered beta contributes nothing.
+shared, only the readout is per-tenant (decentralized multi-task ELM,
+arXiv 1904.11366). The per-block distinct tenant ids are
+scalar-prefetched into SMEM and drive the beta BlockSpec's index map,
+so only the (bl, M) tiles of tenants present in a row block leave HBM.
+Masked padded rows carry tenant id 0; their hidden rows are exact
+zeros so that tenant's beta contributes nothing.
 """
 
 from __future__ import annotations
@@ -66,8 +65,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.elm_stats import hidden_tile
+from repro.kernels.elm_stats import hidden_tile, mxu_precision
 
 
 def _elm_predict_kernel(
@@ -93,6 +93,7 @@ def _elm_predict_kernel(
     y_ref[...] += jax.lax.dot_general(
         h.astype(beta.dtype), beta,
         dimension_numbers=(((1,), (0,)), ((), ())),  # H @ beta
+        precision=mxu_precision(beta.dtype),
         preferred_element_type=jnp.float32,
     )
 
@@ -163,34 +164,58 @@ def elm_predict_pallas(
 
 
 def _elm_predict_stacked_kernel(
-    x_ref, w_ref, b_ref, beta_ref, tid_ref, y_ref,
-    *, activation, num_rows, block_n, operand_dtype,
+    uniq_ref, count_ref, x_ref, w_ref, b_ref, tid_ref, beta_ref, y_ref,
+    h_ref, *, activation, num_rows, block_n, operand_dtype, max_tenants,
 ):
     i = pl.program_id(0)
     l = pl.program_id(1)
+    k = pl.program_id(2)
 
-    @pl.when(l == 0)
+    @pl.when((l == 0) & (k == 0))
     def _init_y():
         y_ref[...] = jnp.zeros_like(y_ref)
 
-    h = hidden_tile(
-        x_ref, w_ref, b_ref,
-        activation=activation,
-        rows_in_tile=num_rows - i * block_n,
-        out_dtype=operand_dtype,
+    # the shared hidden tile, once per (row block, L block)
+    @pl.when(k == 0)
+    def _hidden():
+        h = hidden_tile(
+            x_ref, w_ref, b_ref,
+            activation=activation,
+            rows_in_tile=num_rows - i * block_n,
+            out_dtype=operand_dtype,
+        )
+        h_ref[...] = h.astype(h_ref.dtype)
+
+    # k-th distinct tenant of this row block: its (bl, M) beta tile was
+    # fetched by the index map; only its rows contract against it (the
+    # others add exact zeros, so per-row sums match a per-row gather)
+    @pl.when(k < count_ref[i])
+    def _contract():
+        t = uniq_ref[i * max_tenants + k]
+        h = jnp.where(tid_ref[...] == t, h_ref[...], 0.0)
+        y_ref[...] += jax.lax.dot_general(
+            h, beta_ref[...],
+            dimension_numbers=(((1,), (0,)), ((), ())),  # H @ beta_t
+            precision=mxu_precision(h.dtype),
+            preferred_element_type=jnp.float32,
+        )
+
+
+def _block_tenants(tids, block_n, num_tenants, max_tenants):
+    """Per row block, its distinct tenant ids (ascending, flattened to
+    (blocks * max_tenants,)) and how many there are."""
+    blocks = jnp.sort(tids.reshape(-1, block_n), axis=1)
+    first = jnp.concatenate(
+        [
+            jnp.ones((blocks.shape[0], 1), bool),
+            blocks[:, 1:] != blocks[:, :-1],
+        ],
+        axis=1,
     )
-    betas = beta_ref[...]  # (T, bl, M): whole T axis, L blocked
-    tids = tid_ref[...][:, 0]  # (bn,)
-    bg = jnp.take(betas, tids, axis=0)  # (bn, bl, M) per-row beta tiles
-    # batched row contraction: Y[n] += h[n] @ bg[n] — same dot_general
-    # as the scan/oracle `_gather_contract`, so per-row results do not
-    # depend on launch packing
-    y = jax.lax.dot_general(
-        h.astype(betas.dtype)[:, None, :], bg,
-        dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    )
-    y_ref[...] += y[:, 0, :]
+    count = jnp.sum(first, axis=1, dtype=jnp.int32)
+    uniq = jnp.sort(jnp.where(first, blocks, num_tenants), axis=1)
+    uniq = jnp.minimum(uniq[:, :max_tenants], num_tenants - 1)
+    return uniq.reshape(-1).astype(jnp.int32), count
 
 
 @functools.partial(
@@ -213,7 +238,9 @@ def elm_predict_stacked_pallas(
 
     X: (N, D), W: (D, L), b: (L,), betas: (T, L, M), tenant_ids: (N,)
     int32 -> Y: (N, M) f32. One launch serves every tenant in the
-    batch; the shared hidden tile is computed once per grid step.
+    batch; the shared hidden tile is computed once per (row, L) block
+    and each distinct tenant of the row block costs one (bl, M) beta
+    fetch and one masked MXU contraction.
     """
     N, D = X.shape
     L = W.shape[1]
@@ -228,33 +255,43 @@ def elm_predict_stacked_pallas(
     b2 = jnp.pad(b, (0, pL))[None, :].astype(jnp.float32)
     if pL or pM:
         betas = jnp.pad(betas, ((0, 0), (0, pL), (0, pM)))
-    # padded rows gather tenant 0's beta but their hidden rows are
-    # masked to exact zeros, so the contribution is exactly zero
+    # padded rows carry tenant 0 but their hidden rows are masked to
+    # exact zeros, so the contribution is exactly zero
     tids = jnp.asarray(tenant_ids, jnp.int32)
     if pN:
         tids = jnp.pad(tids, (0, pN))
-    tids2 = tids[:, None]  # (N2, 1): TPU wants >= 2D operands
     W = W.astype(X.dtype)
     betas = betas.astype(jnp.promote_types(X.dtype, betas.dtype))
     N2, L2, M2 = X.shape[0], W.shape[1], betas.shape[2]
-    grid = (N2 // bn, L2 // bl)
+    K = min(T, bn)  # distinct tenants a row block can hold
+    uniq, count = _block_tenants(tids, bn, T, K)
+
+    def beta_block(i, l, k, uniq, count):
+        # past the block's last tenant, repeat it: the block index does
+        # not move, so the pipeline fetches nothing
+        return (uniq[i * K + jnp.minimum(k, count[i] - 1)], l, 0)
+
     kernel = functools.partial(
         _elm_predict_stacked_kernel,
         activation=activation, num_rows=N, block_n=bn,
-        operand_dtype=X.dtype,
+        operand_dtype=X.dtype, max_tenants=K,
     )
     Y = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bn, X.shape[1]), lambda i, l: (i, 0)),   # X
-            pl.BlockSpec((W.shape[0], bl), lambda i, l: (0, l)),   # W
-            pl.BlockSpec((1, bl), lambda i, l: (0, l)),            # b
-            pl.BlockSpec((T, bl, M2), lambda i, l: (0, l, 0)),     # betas
-            pl.BlockSpec((bn, 1), lambda i, l: (i, 0)),            # tids
-        ],
-        out_specs=pl.BlockSpec((bn, M2), lambda i, l: (i, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(N2 // bn, L2 // bl, K),
+            in_specs=[
+                pl.BlockSpec((bn, X.shape[1]), lambda i, l, k, *_: (i, 0)),
+                pl.BlockSpec((W.shape[0], bl), lambda i, l, k, *_: (0, l)),
+                pl.BlockSpec((1, bl), lambda i, l, k, *_: (0, l)),
+                pl.BlockSpec((bn, 1), lambda i, l, k, *_: (i, 0)),  # tids
+                pl.BlockSpec((None, bl, M2), beta_block),
+            ],
+            out_specs=pl.BlockSpec((bn, M2), lambda i, l, k, *_: (i, 0)),
+            scratch_shapes=[pltpu.VMEM((bn, bl), betas.dtype)],
+        ),
         out_shape=jax.ShapeDtypeStruct((N2, M2), jnp.float32),
         interpret=interpret,
-    )(X, W, b2, betas, tids2)
+    )(uniq, count, X, W, b2, tids[:, None], betas)
     return Y[:N, :M]

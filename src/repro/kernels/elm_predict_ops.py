@@ -105,17 +105,17 @@ def predict_map(
     from repro.core.stats import fusable_params
 
     if feature_map is None:
-        return x @ beta
+        return jnp.matmul(x, beta, precision="highest")
     params = fusable_params(feature_map)
     if params is None or jnp.result_type(x, beta) == jnp.float64:
         # non-fusable map (deep backbone) or the f64 fidelity path:
         # materialize H for this call only
-        return feature_map(x) @ beta
+        return jnp.matmul(feature_map(x), beta, precision="highest")
     W, b, activation = params
     lead = x.shape[:-1]
     rows = x.reshape(-1, x.shape[-1])
     if rows.shape[0] == 0:  # the tiled paths cannot grid over N = 0
-        return feature_map(x) @ beta
+        return jnp.matmul(feature_map(x), beta, precision="highest")
     Y = fused_predict(
         rows, W, b, beta, activation=activation, use_kernel=use_kernel,
         tuning=tuning, **kw,

@@ -47,6 +47,23 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+#: Scoped VMEM for the moment kernels. An f32 dot at HIGHEST keeps bf16
+#: splits of its operands in VMEM beside the double-buffered blocks: at
+#: the default tiles and D = 784, the node-batched (vmapped) f32 kernel
+#: needs just over the 16 MiB default when compiled for a TPU v5e.
+_VMEM_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=32 * 2**20)
+
+
+def mxu_precision(dtype):
+    """Full f32 contraction for f32 operands, the default otherwise.
+
+    Mosaic's default contracts f32 operands in a single bf16 pass
+    (~1e-3 relative error, measured on a TPU v5e); an f32 caller asked
+    for f32, so every in-kernel dot on f32 operands passes HIGHEST.
+    """
+    return jax.lax.Precision.HIGHEST if dtype == jnp.float32 else None
 
 
 def hidden_tile(x_ref, w_ref, b_ref, *, activation, rows_in_tile, out_dtype):
@@ -63,6 +80,7 @@ def hidden_tile(x_ref, w_ref, b_ref, *, activation, rows_in_tile, out_dtype):
     s = jax.lax.dot_general(
         x, w,
         dimension_numbers=(((1,), (0,)), ((), ())),
+        precision=mxu_precision(x.dtype),
         preferred_element_type=jnp.float32,
     )
     b = b_ref[...].astype(jnp.float32)  # (1, bl): bias, or gamma for rbf
@@ -105,39 +123,51 @@ def _elm_stats_kernel(
         activation=activation, rows_in_tile=rows_in_tile,
         out_dtype=operand_dtype,
     )
+    _accumulate(
+        lambda: tile(wi_ref, bi_ref), lambda: tile(wj_ref, bj_ref),
+        t_ref, p_ref, q_ref, i=i, j=j, symmetric=symmetric,
+    )
 
-    def _accum():
-        h_i = tile(wi_ref, bi_ref)
-        if symmetric:
+
+def _accumulate(tile_i, tile_j, t_ref, p_ref, q_ref, *, i, j, symmetric):
+    """P[i, j] += H_i^T H_j, and Q[i] += H_i^T T once per (i, n).
+
+    Q accumulates on the diagonal visit (symmetric mode) or at j == 0,
+    reusing that visit's h_i. That visit is a branch of its own which
+    builds its own h_i: once the grid has more than one L block, Mosaic
+    refuses an f32 tile that feeds one transposed-LHS matmul and, under
+    a nested conditional, a second one (its MXU transpose-reuse
+    transform trips on the shared producer).
+    """
+    TN = (((0,), (0,)), ((), ()))  # contract rows: H_i^T @ ·
+
+    def visit(with_q: bool, diagonal: bool):
+        def body():
+            h_i = tile_i()
             # on the diagonal the j-tile IS the i-tile — reuse it
-            h_j = jax.lax.cond(
-                i == j, lambda: h_i, lambda: tile(wj_ref, bj_ref)
-            )
-        else:
-            h_j = tile(wj_ref, bj_ref)
-        p_ref[...] += jax.lax.dot_general(
-            h_i, h_j,
-            dimension_numbers=(((0,), (0,)), ((), ())),  # H_i^T H_j
-            preferred_element_type=jnp.float32,
-        )
-
-        # Accumulate Q once per (i, n), reusing h_i: on the diagonal
-        # visit in symmetric mode (always computed), at j == 0
-        # otherwise. T may be wider than the operand dtype (f32 targets
-        # with bf16 features) — promote h rather than quantize T.
-        @pl.when(j == (i if symmetric else 0))
-        def _accum_q():
-            t = t_ref[...]
-            q_ref[...] += jax.lax.dot_general(
-                h_i.astype(t.dtype), t,
-                dimension_numbers=(((0,), (0,)), ((), ())),  # H_i^T T
+            h_j = h_i if diagonal else tile_j()
+            p_ref[...] += jax.lax.dot_general(
+                h_i, h_j, TN, precision=mxu_precision(h_i.dtype),
                 preferred_element_type=jnp.float32,
             )
+            if with_q:
+                # T may be wider than the operand dtype (f32 targets
+                # with bf16 features) — promote h rather than quantize T
+                t = t_ref[...]
+                q_ref[...] += jax.lax.dot_general(
+                    h_i.astype(t.dtype), t, TN,
+                    precision=mxu_precision(t.dtype),
+                    preferred_element_type=jnp.float32,
+                )
+
+        return body
 
     if symmetric:
-        pl.when(i <= j)(_accum)
+        pl.when(i == j)(visit(with_q=True, diagonal=True))
+        pl.when(i < j)(visit(with_q=False, diagonal=False))
     else:
-        _accum()
+        pl.when(j == 0)(visit(with_q=True, diagonal=False))
+        pl.when(j != 0)(visit(with_q=False, diagonal=False))
 
 
 def preact_tile(z_ref, b_ref, *, activation, rows_in_tile, out_dtype):
@@ -184,35 +214,10 @@ def _elm_preact_kernel(
         activation=activation, rows_in_tile=rows_in_tile,
         out_dtype=operand_dtype,
     )
-
-    def _accum():
-        h_i = tile(zi_ref, bi_ref)
-        if symmetric:
-            # on the diagonal the j-tile IS the i-tile — reuse it
-            h_j = jax.lax.cond(
-                i == j, lambda: h_i, lambda: tile(zj_ref, bj_ref)
-            )
-        else:
-            h_j = tile(zj_ref, bj_ref)
-        p_ref[...] += jax.lax.dot_general(
-            h_i, h_j,
-            dimension_numbers=(((0,), (0,)), ((), ())),  # H_i^T H_j
-            preferred_element_type=jnp.float32,
-        )
-
-        @pl.when(j == (i if symmetric else 0))
-        def _accum_q():
-            t = t_ref[...]
-            q_ref[...] += jax.lax.dot_general(
-                h_i.astype(t.dtype), t,
-                dimension_numbers=(((0,), (0,)), ((), ())),  # H_i^T T
-                preferred_element_type=jnp.float32,
-            )
-
-    if symmetric:
-        pl.when(i <= j)(_accum)
-    else:
-        _accum()
+    _accumulate(
+        lambda: tile(zi_ref, bi_ref), lambda: tile(zj_ref, bj_ref),
+        t_ref, p_ref, q_ref, i=i, j=j, symmetric=symmetric,
+    )
 
 
 @functools.partial(
@@ -277,6 +282,7 @@ def elm_preact_stats_pallas(
             jax.ShapeDtypeStruct((L2, L2), jnp.float32),
             jax.ShapeDtypeStruct((L2, M2), jnp.float32),
         ],
+        compiler_params=_VMEM_PARAMS,
         interpret=interpret,
     )(Z, Z, b2, b2, T)
     P = P[:L, :L]
@@ -359,6 +365,7 @@ def elm_stats_pallas(
             jax.ShapeDtypeStruct((L2, L2), jnp.float32),
             jax.ShapeDtypeStruct((L2, M2), jnp.float32),
         ],
+        compiler_params=_VMEM_PARAMS,
         interpret=interpret,
     )(X, W, W, b2, b2, T)
     P = P[:L, :L]

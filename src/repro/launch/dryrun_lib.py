@@ -17,7 +17,6 @@ import jax
 from repro.analysis.roofline import roofline_from_compiled
 from repro.configs import INPUT_SHAPES, get as get_config
 from repro.configs.base import ArchConfig, InputShape
-from repro.utils import compat
 from repro.distributed import sharding as shd
 from repro.distributed.steps import make_serve_bundle, make_train_bundle, jit_train_step
 from repro.launch import specs as specs_lib
@@ -136,7 +135,7 @@ def run_combo(
         mesh = make_production_mesh(multi_pod=multi_pod)
         chips = mesh.devices.size
         t0 = time.time()
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             if shape.kind == "train":
                 lowered = _lower_train(cfg, shape, mesh, microbatches)
             elif shape.kind == "prefill":

@@ -114,10 +114,8 @@ def constrain_batch_dim(x: jax.Array, cfg: ArchConfig) -> jax.Array:
     replicated. Active under act_shard == "batch"."""
     if cfg.act_shard != "batch":
         return x
-    from repro.utils import compat
-
-    mesh = compat.get_abstract_mesh()
-    names = getattr(mesh, "axis_names", ()) if mesh is not None else ()
+    mesh = jax.sharding.get_abstract_mesh()
+    names = mesh.axis_names
     if "model" not in names:
         return x
     n = dict(mesh.shape)["model"]
@@ -139,10 +137,8 @@ def constrain_acts(h: jax.Array, cfg: ArchConfig) -> jax.Array:
     """
     if cfg.act_shard == "none":
         return h
-    from repro.utils import compat
-
-    mesh = compat.get_abstract_mesh()
-    names = getattr(mesh, "axis_names", ()) if mesh is not None else ()
+    mesh = jax.sharding.get_abstract_mesh()
+    names = mesh.axis_names
     if "model" not in names:
         return h
     n = dict(mesh.shape)["model"]

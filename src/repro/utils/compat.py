@@ -1,10 +1,7 @@
-"""JAX version compatibility shims.
+"""Mesh and shard_map construction in one place.
 
-The codebase targets the modern public API (``jax.shard_map``,
-``jax.make_mesh(..., axis_types=...)``); older installs (<= 0.4.x) only
-ship ``jax.experimental.shard_map`` and a ``make_mesh`` without
-``axis_types``. Everything that builds meshes or shard_maps goes through
-this module so the rest of the code stays version-agnostic.
+Everything that builds meshes or shard_maps goes through this module,
+so the call sites share one set of defaults (Auto mesh axis types).
 """
 
 from __future__ import annotations
@@ -14,63 +11,19 @@ from typing import Sequence
 import jax
 
 
-def shard_map(f, mesh, in_specs, out_specs):
-    """``jax.shard_map`` on new JAX, experimental fallback on old.
-
-    The fallback disables the replication checker: it predates the
-    rewrite rules for ``ppermute``-heavy programs like the gossip
-    schedules and rejects them spuriously.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
+def shard_map(f, mesh, in_specs, out_specs, *, check_vma=True):
+    """``jax.shard_map`` over ``mesh``. ``check_vma=False`` for bodies
+    that call Pallas kernels, whose outputs carry no varying-axes type."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=check_vma,
     )
 
 
-def get_abstract_mesh():
-    """The mesh currently in context, or None.
-
-    New JAX exposes ``jax.sharding.get_abstract_mesh``; on old JAX the
-    nearest equivalent is the thread-local physical mesh set by a
-    ``with mesh:`` block. Callers treat None / no-axes as "no mesh in
-    context" and skip sharding hints, which keeps semantics identical.
-    """
-    fn = getattr(jax.sharding, "get_abstract_mesh", None)
-    if fn is not None:
-        return fn()
-    try:
-        from jax._src import mesh as mesh_lib
-
-        m = mesh_lib.thread_resources.env.physical_mesh
-        return None if m.empty else m
-    except Exception:  # pragma: no cover - very old/changed internals
-        return None
-
-
-def set_mesh(mesh):
-    """Context manager putting ``mesh`` in scope for sharding hints.
-
-    ``jax.set_mesh`` on new JAX; on old JAX a ``Mesh`` is itself the
-    (thread-local) context manager.
-    """
-    fn = getattr(jax, "set_mesh", None)
-    if fn is not None:
-        return fn(mesh)
-    return mesh
-
-
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str]):
-    """``jax.make_mesh`` with Auto axis types where supported."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(
-            tuple(axis_shapes),
-            tuple(axis_names),
-            axis_types=(axis_type.Auto,) * len(tuple(axis_names)),
-        )
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names))
+    """``jax.make_mesh`` with Auto axis types."""
+    names = tuple(axis_names)
+    return jax.make_mesh(
+        tuple(axis_shapes), names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(names),
+    )

@@ -17,6 +17,8 @@ def run_py(code: str, *, devices: int = 1, timeout: int = 600):
     env = dict(os.environ)
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     if devices > 1:
+        # fake host devices are CPU devices: never reach for a chip
+        env["JAX_PLATFORMS"] = "cpu"
         env["XLA_FLAGS"] = (
             f"--xla_force_host_platform_device_count={devices}"
         )

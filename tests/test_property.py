@@ -130,7 +130,7 @@ def _dtype_ctx(dtype: str):
     import contextlib
 
     if dtype == "float64":
-        return jax.experimental.enable_x64()
+        return jax.enable_x64()
     return contextlib.nullcontext()
 
 

@@ -259,7 +259,7 @@ def test_simulate_init_raw_matches_hidden_path():
 def test_f64_dtype_policy():
     """x64 fidelity inputs keep f64 moments (the stiff-C paper runs)."""
     fmap, X, T = _problem(50, 3, 8, 1, seed=8)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64():
         X64 = jnp.asarray(np.asarray(X), jnp.float64)
         T64 = jnp.asarray(np.asarray(T), jnp.float64)
         fmap64 = features.RandomFeatureMap(

@@ -142,7 +142,7 @@ def test_spanning_tree_bfs():
 def test_vertical_stats_bitwise_f64_vs_centralized():
     """Acceptance: assembled (P, Q) from column-sliced nodes matches
     the centralized horizontal stats plane bitwise in f64."""
-    with jax.experimental.enable_x64():
+    with jax.enable_x64():
         rng = np.random.default_rng(1)
         N, D, L, M, V = 150, 11, 24, 2, 4
         X = jnp.asarray(rng.standard_normal((N, D)), jnp.float64)
