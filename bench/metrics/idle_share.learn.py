@@ -1,0 +1,5 @@
+"""Device: share of the learning window in which no program ran."""
+
+
+def read(ctx):
+    return 100.0 * ctx.trace.idle_share

@@ -1,0 +1,7 @@
+"""Whole streamed chunk (feature map, Woodbury, re-seed, rounds): the
+useful FLOPs over the window, the chips and the bf16 peak."""
+
+
+def read(ctx):
+    peak = ctx.chips * ctx.peak["bf16_flops"]
+    return 100.0 * ctx.counters["useful_flops"] / (ctx.trace.window_s * peak)
