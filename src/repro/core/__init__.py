@@ -16,6 +16,7 @@ Modules:
   dsgd        beyond-paper decentralized deep training (paper rule on pytrees)
   incremental Hamiltonian-cycle baseline (Sec. II-B1)
   fusion_elm  fusion-center / MapReduce baseline (refs [17][18])
+  scopes      device-side names of the DC-ELM phases (jax.named_scope)
 """
 
 from repro.core import (  # noqa: F401
@@ -31,5 +32,6 @@ from repro.core import (  # noqa: F401
     incremental,
     online,
     push_sum,
+    scopes,
     stats,
 )

@@ -370,6 +370,7 @@ class AsyncEngine:
             per_round_bytes=np.asarray(per_fire_bytes, dtype=np.int64),
         )
         compression.record_wire_stats(self, stats)
+        self.total_bytes_on_wire += stats.bytes_on_wire
 
     # ------------------------------------------------------------------ api
 

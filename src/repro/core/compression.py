@@ -353,15 +353,12 @@ def dense_out_degrees(adjacencies) -> np.ndarray:
 
 
 def record_wire_stats(mixer, stats: WireStats | None) -> None:
-    """Store a run's WireStats on a mixer and accumulate its byte
-    counter — the one place the storage convention lives (uses
-    ``object.__setattr__`` so frozen-dataclass mixers work too)."""
+    """Store a run's WireStats on a mixer — the one place the storage
+    convention lives (uses ``object.__setattr__`` so frozen-dataclass
+    mixers work too). The stats are counted from shapes when ``run`` is
+    traced, so under ``jax.jit`` they describe the program, once, and
+    are not a tally of the rounds it later executes."""
     object.__setattr__(mixer, "last_wire_stats", stats)
-    if stats is not None:
-        object.__setattr__(
-            mixer, "total_bytes_on_wire",
-            getattr(mixer, "total_bytes_on_wire", 0) + stats.bytes_on_wire,
-        )
 
 
 def compute_wire_stats(
@@ -417,8 +414,7 @@ class CompressedMixer:
     The compiled ``shard_map(scan)`` program is cached (keyed by
     rule/rounds/specs) so streaming events and spec sweeps compile
     once. ``run`` records exact wire accounting on
-    ``self.last_wire_stats`` (surfaced as ``ConsensusEngine.wire_stats``)
-    and accumulates ``total_bytes_on_wire`` across calls.
+    ``self.last_wire_stats`` (surfaced as ``ConsensusEngine.wire_stats``).
 
     ``laplacian``/``step`` are stateless (each call behaves like a
     refresh round: absolute encode, no replicas, no event gating); the
@@ -445,7 +441,6 @@ class CompressedMixer:
             )
         self.base = base
         self.last_wire_stats: WireStats | None = None
-        self.total_bytes_on_wire = 0
         self._programs: dict = {}
         # replica memory persists across run()/stream_chunk() calls on
         # this mixer: x̂ is real protocol state (what the network has

@@ -53,6 +53,7 @@ from repro.core.mixers import (
     NeighborMixer,
     PpermuteMixer,
 )
+from repro.core.scopes import phase
 from repro.utils import compat
 
 
@@ -229,10 +230,11 @@ class ConsensusEngine:
         (final_state, traces or None).
         """
         self._validate_gamma(gamma, check_gamma)
-        return self.mixer.run(
-            self.rule, x, aux, gamma, num_iters, trace_fn, state_spec,
-            aux_spec,
-        )
+        with phase("rounds"):
+            return self.mixer.run(
+                self.rule, x, aux, gamma, num_iters, trace_fn, state_spec,
+                aux_spec,
+            )
 
     # -- streaming (paper Algorithm 2) ------------------------------------
 
@@ -326,9 +328,10 @@ class ConsensusEngine:
         Node-level churn (a whole member arriving/departing, not just
         its data chunks) is ``stream_leave``/``stream_join``, which
         rebuild the engine for the new V. After the event,
-        ``self.wire_stats`` holds the exact bytes the rounds moved
-        (and the mixer accumulates ``total_bytes_on_wire`` across
-        events).
+        ``self.wire_stats`` holds the exact bytes the event's rounds
+        move, counted from shapes when the rounds are traced (so under
+        ``jax.jit`` it is set when the program is traced, not per
+        executed event).
 
         publish_to: optional ``serving.BetaStore`` (anything with a
         ``publish(betas)`` method) — the post-consensus stacked betas

@@ -24,6 +24,8 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
+from repro.core.scopes import phase
+
 Activation = Callable[[jax.Array], jax.Array]
 
 # The shared activation registry (name -> elementwise g). "rbf" is not
@@ -80,8 +82,9 @@ class RandomFeatureMap:
     def __call__(self, x: jax.Array) -> jax.Array:
         """x: (..., D) -> H: (..., L)."""
         g = ACTIVATIONS[self.activation]
-        z = jnp.matmul(x, self.weights, precision="highest")
-        return g(z + self.bias)
+        with phase("features"):
+            z = jnp.matmul(x, self.weights, precision="highest")
+            return g(z + self.bias)
 
 
 def rbf_squared_dists(
@@ -118,7 +121,8 @@ class RBFFeatureMap:
         return self.centers.shape[0]
 
     def __call__(self, x: jax.Array) -> jax.Array:
-        return jnp.exp(-self.gamma * rbf_squared_dists(x, self.centers))
+        with phase("features"):
+            return jnp.exp(-self.gamma * rbf_squared_dists(x, self.centers))
 
 
 def make_random_features(

@@ -116,7 +116,6 @@ class DenseMixer:
         self.degrees = jnp.sum(adjacencies, axis=-1)
         self.compress = _normalize_compress(compress)
         self.last_wire_stats = None
-        self.total_bytes_on_wire = 0
 
     @classmethod
     def from_graphs(
@@ -387,7 +386,6 @@ class PpermuteMixer:
         # written through compression.record_wire_stats
         object.__setattr__(self, "compress", _normalize_compress(self.compress))
         object.__setattr__(self, "last_wire_stats", None)
-        object.__setattr__(self, "total_bytes_on_wire", 0)
 
     def _record_wire(self, x, num_iters: int) -> None:
         from repro.core import compression
@@ -546,7 +544,6 @@ class FaultyMixer:
         self.edge_keep = edge_keep
         self.num_rounds = edge_keep.shape[0]
         self.last_wire_stats = None
-        self.total_bytes_on_wire = 0
         if isinstance(base, DenseMixer):
             S = base.adjacencies.shape[0]
             R = edge_keep.shape[0]

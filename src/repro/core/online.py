@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import stats as stats_lib
+from repro.core.scopes import phase
 
 
 @jax.tree_util.register_dataclass
@@ -76,19 +77,21 @@ def woodbury_remove(omega: jax.Array, dH: jax.Array) -> jax.Array:
 @jax.jit
 def remove_chunk(state: OnlineNodeState, dH: jax.Array, dT: jax.Array):
     """Algorithm 2, steps 5-8."""
-    return OnlineNodeState(
-        omega=woodbury_remove(state.omega, dH),
-        Q=state.Q - jnp.matmul(dH.T, dT, precision="highest"),
-    )
+    with phase("woodbury"):
+        return OnlineNodeState(
+            omega=woodbury_remove(state.omega, dH),
+            Q=state.Q - jnp.matmul(dH.T, dT, precision="highest"),
+        )
 
 
 @jax.jit
 def add_chunk(state: OnlineNodeState, dH: jax.Array, dT: jax.Array):
     """Algorithm 2, steps 9-12."""
-    return OnlineNodeState(
-        omega=woodbury_add(state.omega, dH),
-        Q=state.Q + jnp.matmul(dH.T, dT, precision="highest"),
-    )
+    with phase("woodbury"):
+        return OnlineNodeState(
+            omega=woodbury_add(state.omega, dH),
+            Q=state.Q + jnp.matmul(dH.T, dT, precision="highest"),
+        )
 
 
 def update_chunk(
@@ -194,9 +197,10 @@ batched_rescale_num_nodes = jax.jit(
 
 def reseed_betas(states: OnlineNodeState) -> jax.Array:
     """Stacked beta_i = Omega_i Q_i after an online update (step 13)."""
-    return jnp.einsum(
-        "vlk,vkm->vlm", states.omega, states.Q, precision="highest"
-    )
+    with phase("reseed"):
+        return jnp.einsum(
+            "vlk,vkm->vlm", states.omega, states.Q, precision="highest"
+        )
 
 
 @functools.partial(jax.jit, static_argnames=("C", "V"))

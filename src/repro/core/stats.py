@@ -45,6 +45,7 @@ import jax.numpy as jnp
 from jax.scipy.linalg import cho_factor, cho_solve
 
 from repro.core.features import RandomFeatureMap, RBFFeatureMap
+from repro.core.scopes import phase
 
 
 def accum_dtype(*operands) -> jnp.dtype:
@@ -104,16 +105,18 @@ def raw_moments(
     feature map is affine/RBF and the accumulator is f32."""
     dtype = accum_dtype(X, T) if dtype is None else jnp.dtype(dtype)
     params = fusable_params(feature_map)
-    if params is not None and dtype == jnp.float32:
-        from repro.kernels import elm_stats_ops
+    with phase("stats"):
+        if params is not None and dtype == jnp.float32:
+            from repro.kernels import elm_stats_ops
 
-        W, b, activation = params
-        return elm_stats_ops.fused_moments(
-            X, W, b, T, activation=activation, use_kernel=use_kernel, **kw
-        )
-    # non-fusable feature map (deep backbone) or f64 fidelity path:
-    # materialize H for this call only — callers chunk N
-    return hidden_moments(feature_map(X), T, dtype=dtype)
+            W, b, activation = params
+            return elm_stats_ops.fused_moments(
+                X, W, b, T, activation=activation, use_kernel=use_kernel,
+                **kw,
+            )
+        # non-fusable feature map (deep backbone) or f64 fidelity path:
+        # materialize H for this call only — callers chunk N
+        return hidden_moments(feature_map(X), T, dtype=dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -277,8 +280,9 @@ def omega_from_moments(P: jax.Array, C: float, V: int = 1) -> jax.Array:
     both flops and accuracy for the paper's stiff C values.
     """
     L = P.shape[-1]
-    eye = jnp.eye(L, dtype=P.dtype)
-    return spd_solve(eye / (V * C) + P, eye)
+    with phase("omega"):
+        eye = jnp.eye(L, dtype=P.dtype)
+        return spd_solve(eye / (V * C) + P, eye)
 
 
 def finalize_moments(P: jax.Array, Q: jax.Array, C: float, V: int = 1):

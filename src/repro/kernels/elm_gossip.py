@@ -248,6 +248,7 @@ def elm_gossip_pallas(
             round_vmem_bytes(V, L, M, bv, payload=payload is not None)
         ),
         interpret=interpret,
+        name="elm_gossip_pallas",
     )
 
     if payload is None:
@@ -304,6 +305,7 @@ def elm_gossip_pallas_multiround(
         scratch_shapes=[pltpu.VMEM((Vp, Mp, Lp), jnp.float32)],
         compiler_params=_vmem_params(multiround_vmem_bytes(V, L, M, S, d_max)),
         interpret=interpret,
+        name="elm_gossip_pallas_multiround",
     )(ip.reshape(-1), wp.reshape(-1), dg.reshape(-1), scale, bt, om)
     return _unpack(out, V, L, M, betas.dtype)
 

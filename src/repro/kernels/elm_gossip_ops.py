@@ -99,6 +99,23 @@ _round_payload_jit = jax.jit(
 )
 
 
+@functools.cache
+def _pallas_jit(multiround: bool):
+    """The jitted Pallas arm, named after its entry point in traces
+    (``jit(elm_gossip_pallas)``, not a ``jit(<unknown>)`` partial)."""
+    from repro.kernels import elm_gossip
+
+    if multiround:
+        return jax.jit(
+            elm_gossip.elm_gossip_pallas_multiround,
+            static_argnames=("num_rounds", "compress", "interpret"),
+        )
+    return jax.jit(
+        elm_gossip.elm_gossip_pallas,
+        static_argnames=("num_rounds", "compress", "block_v", "interpret"),
+    )
+
+
 def _resolve(kw, tuning, *, V, d_max, L, M, dtype, impl):
     cfg = autotune.resolve_config(
         kw, tuning, op="gossip", impl=impl,
@@ -130,8 +147,6 @@ def fused_gossip_rounds(
                 "takes block_v="
             )
         from repro.kernels.elm_gossip import (
-            elm_gossip_pallas,
-            elm_gossip_pallas_multiround,
             fit_block_v,
             multiround_vmem_bytes,
         )
@@ -149,20 +164,14 @@ def fused_gossip_rounds(
             multiround_vmem_bytes(V, L, M, S, d_max)
             <= autotune.VMEM_BUDGET
         ):
-            fn = jax.jit(
-                functools.partial(
-                    elm_gossip_pallas_multiround, num_rounds=num_rounds,
-                    compress=compress, interpret=interp,
-                )
+            return _pallas_jit(True)(
+                betas, omegas, idx, w, deg, scale, num_rounds=num_rounds,
+                compress=compress, interpret=interp,
             )
-        else:
-            fn = jax.jit(
-                functools.partial(
-                    elm_gossip_pallas, num_rounds=num_rounds,
-                    compress=compress, block_v=int(bv), interpret=interp,
-                )
-            )
-        return fn(betas, omegas, idx, w, deg, scale)
+        return _pallas_jit(False)(
+            betas, omegas, idx, w, deg, scale, num_rounds=num_rounds,
+            compress=compress, block_v=int(bv), interpret=interp,
+        )
     if block_v is not None:
         raise ValueError(
             "block_v= is the Pallas arm's knob; the scan fallback "

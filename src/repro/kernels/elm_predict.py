@@ -159,6 +159,7 @@ def elm_predict_pallas(
         out_specs=pl.BlockSpec((bn, M2), lambda i, l: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((N2, M2), jnp.float32),
         interpret=interpret,
+        name="elm_predict_pallas",
     )(X, W, b2, beta)
     return Y[:N, :M]
 
@@ -293,5 +294,6 @@ def elm_predict_stacked_pallas(
         ),
         out_shape=jax.ShapeDtypeStruct((N2, M2), jnp.float32),
         interpret=interpret,
+        name="elm_predict_stacked_pallas",
     )(uniq, count, X, W, b2, tids[:, None], betas)
     return Y[:N, :M]

@@ -284,6 +284,7 @@ def elm_preact_stats_pallas(
         ],
         compiler_params=_VMEM_PARAMS,
         interpret=interpret,
+        name="elm_preact_stats_pallas",
     )(Z, Z, b2, b2, T)
     P = P[:L, :L]
     Q = Q[:L, :M]
@@ -367,6 +368,7 @@ def elm_stats_pallas(
         ],
         compiler_params=_VMEM_PARAMS,
         interpret=interpret,
+        name="elm_stats_pallas",
     )(X, W, W, b2, b2, T)
     P = P[:L, :L]
     Q = Q[:L, :M]

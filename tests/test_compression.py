@@ -301,14 +301,15 @@ def test_stream_chunk_threads_wire_stats():
     spec = CompressionSpec(mode="int8", tile=128)
     eng = engine.simulated_dc_elm(g, C, compress=spec)
     st0 = eng.stream_init(H, T)
-    before = eng.mixer.total_bytes_on_wire
+    assert eng.mixer.last_wire_stats is None  # no rounds yet
     dH = jax.random.normal(jax.random.key(9), (8, 4, 32)) / np.sqrt(32)
     dT = jax.random.normal(jax.random.key(10), (8, 4, 4))
     eng.stream_chunk(st0, added=(dH, dT), gamma=g.default_gamma(),
                      num_iters=12)
     ws = eng.wire_stats
     assert ws is not None and ws.rounds == 12
-    assert eng.mixer.total_bytes_on_wire == before + ws.bytes_on_wire
+    assert eng.mixer.last_wire_stats is ws
+    assert ws.bytes_on_wire == int(ws.per_round_bytes.sum()) > 0
 
 
 # ---------------------------------------------------------------------------

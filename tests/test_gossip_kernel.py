@@ -334,7 +334,7 @@ def test_neighbor_engine_matches_dense(compress):
     fn, _ = en.run(st.betas, st.omegas, gamma, 20)
     np.testing.assert_allclose(fn, fd, **TOL)
     assert en.wire_stats is not None
-    assert en.mixer.total_bytes_on_wire > 0
+    assert en.mixer.last_wire_stats.bytes_on_wire > 0
 
 
 @pytest.mark.parametrize("compress", [None, "bf16"])
@@ -350,7 +350,7 @@ def test_neighbor_engine_fused_arm_parity(compress):
     fd, _ = ed.run(st.betas, st.omegas, gamma, 12)
     fn, _ = en.run(st.betas, st.omegas, gamma, 12)
     np.testing.assert_allclose(fn, fd, **TOL)
-    assert en.mixer.total_bytes_on_wire > 0
+    assert en.mixer.last_wire_stats.bytes_on_wire > 0
 
 
 def test_neighbor_engine_fused_int8_round():

@@ -13,6 +13,7 @@ that runs this file loads the TPU library.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -124,3 +125,28 @@ def test_kernel_compiles_for_v5e(one_chip, name):
     ]
     compiled = jax.jit(kernel).lower(*shapes).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+#: the instruction name of each kernel's launches, which a device trace
+#: shows (bench/metrics/stats_roofline.learn.py matches "elm_stats_pallas")
+KERNEL_NAMES = {
+    "stats_f32_nodes64": "elm_stats_pallas",
+    "preact_stats_f32": "elm_preact_stats_pallas",
+    "predict_f32": "elm_predict_pallas",
+    "predict_stacked_T64": "elm_predict_stacked_pallas",
+    "gossip_round_v1024": "elm_gossip_pallas",
+    "gossip_multiround_v16": "elm_gossip_pallas_multiround",
+}
+_CUSTOM_CALL = re.compile(r'%(\S+) = [^\n]*custom_call_target="tpu_custom_call"')
+
+
+@pytest.mark.parametrize("name", sorted(KERNEL_NAMES))
+def test_kernel_instruction_name(one_chip, name):
+    kernel, args = CASES[name]
+    shapes = [
+        jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+        for shape, dtype in args
+    ]
+    text = jax.jit(kernel).lower(*shapes).compile().as_text()
+    names = _CUSTOM_CALL.findall(text)
+    assert names and all(KERNEL_NAMES[name] in n for n in names), names
