@@ -174,7 +174,8 @@ def _snapshot(arr, k):
 def round_vmem_bytes(V, L, M, block_v, *, payload=False) -> int:
     """VMEM the per-round arm keeps: the whole gather source (single
     buffered — its block never moves), and double-buffered per-block
-    state, Omega and output tiles."""
+    state, Omega and output tiles. ``_vmem_params`` requests this plus
+    a margin; ``fit_block_v`` holds it to ``vmem_budget()``."""
     Vp, Lp, Mp = _rup(V, block_v), _rup(L, 128), _rup(M, 8)
     state = 4 * Vp * Mp * Lp
     tiles = 2 * 4 * block_v * (2 * Mp * Lp + Lp * Lp)
@@ -183,16 +184,40 @@ def round_vmem_bytes(V, L, M, block_v, *, payload=False) -> int:
 
 def fit_block_v(V, L, M, block_v, budget, *, payload=False) -> int:
     """The largest node block <= ``block_v`` whose resident set fits
-    ``budget`` (1 when none does: the Omega tile is then the floor)."""
+    ``budget`` (1 when none does: the Omega tile is then the floor).
+    The dispatchers pass ``vmem_budget()``, the VMEM the kernel may
+    request, not the 16 MiB default scoped limit: at V = 1024, L = 256
+    the gather source alone fills that, and the block would fall to 1."""
     bv = max(1, min(int(block_v), V))
     while bv > 1 and round_vmem_bytes(V, L, M, bv, payload=payload) > budget:
         bv //= 2
     return bv
 
 
+#: VMEM per TensorCore of TPU v5e, the chip this repo targets, for when
+#: the default device is no TPU (source: the "TPU v5 lite" entry of
+#: ``jax.experimental.pallas.tpu.get_tpu_info``, JAX 0.9)
+_V5E_VMEM_BYTES = 128 * 2**20
+
+
+def vmem_budget() -> int:
+    """The VMEM a per-round node block may request: half the core's
+    capacity, the other half left to Mosaic's own scratch and the
+    ``_vmem_params`` margin. Read from the default device where JAX
+    describes it, else v5e's capacity (off TPU the block only sizes
+    interpret-mode runs, whose outputs do not depend on it)."""
+    try:
+        capacity = pltpu.get_tpu_info().vmem_capacity_bytes
+    except ValueError:  # not a TPU device kind
+        capacity = _V5E_VMEM_BYTES
+    return capacity // 2
+
+
 def _vmem_params(nbytes: int):
-    """Raise the scoped VMEM limit only when the resident set needs it
-    (the default scoped limit is 16 MiB; v5e has 128 MiB per core)."""
+    """Raise the scoped VMEM limit to the resident set plus a 4 MiB
+    margin when that passes the 16 MiB default. The default is only a
+    starting point: blocks are sized against ``vmem_budget()``, the
+    most this may ask for."""
     limit = nbytes + 4 * 2**20
     if limit <= 16 * 2**20:
         return None

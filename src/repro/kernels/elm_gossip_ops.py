@@ -149,6 +149,7 @@ def fused_gossip_rounds(
         from repro.kernels.elm_gossip import (
             fit_block_v,
             multiround_vmem_bytes,
+            vmem_budget,
         )
 
         cfg = _resolve(
@@ -158,7 +159,7 @@ def fused_gossip_rounds(
         bv = cfg.get("block_n") or autotune.DEFAULTS[
             ("gossip", "pallas")
         ]["block_n"]
-        bv = fit_block_v(V, L, M, bv, autotune.VMEM_BUDGET)
+        bv = fit_block_v(V, L, M, bv, vmem_budget())
         interp = (not _on_tpu()) if interpret is None else interpret
         if (
             multiround_vmem_bytes(V, L, M, S, d_max)
@@ -207,7 +208,11 @@ def fused_gossip_round(
     if betas.dtype != jnp.float32 or payload.dtype != jnp.float32:
         use = False
     if use:
-        from repro.kernels.elm_gossip import elm_gossip_pallas, fit_block_v
+        from repro.kernels.elm_gossip import (
+            elm_gossip_pallas,
+            fit_block_v,
+            vmem_budget,
+        )
 
         cfg = _resolve(
             {"block_n": block_v}, tuning,
@@ -216,7 +221,7 @@ def fused_gossip_round(
         bv = cfg.get("block_n") or autotune.DEFAULTS[
             ("gossip", "pallas")
         ]["block_n"]
-        bv = fit_block_v(V, L, M, bv, autotune.VMEM_BUDGET, payload=True)
+        bv = fit_block_v(V, L, M, bv, vmem_budget(), payload=True)
         interp = (not _on_tpu()) if interpret is None else interpret
         return elm_gossip_pallas(
             betas, omegas, idx_k[None], w_k[None], deg_k[None], scale,

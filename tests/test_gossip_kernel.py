@@ -27,7 +27,10 @@ from repro.kernels import elm_gossip_ref as ref
 from repro.kernels.elm_gossip import (
     elm_gossip_pallas,
     elm_gossip_pallas_multiround,
+    fit_block_v,
     multiround_vmem_bytes,
+    round_vmem_bytes,
+    vmem_budget,
 )
 
 TOL = dict(rtol=2e-5, atol=2e-5)
@@ -200,6 +203,41 @@ def test_pallas_scanned_rounds_match_scan(compress):
         compress=compress, interpret=True,
     )
     np.testing.assert_allclose(got, want, **TOL)
+
+
+@pytest.mark.interpret
+@pytest.mark.parametrize("compress", [None, "bf16"])
+@pytest.mark.parametrize("V", [8, 10])  # 10: blocks of 4 and 8 pad
+def test_pallas_rounds_bitwise_equal_across_blocks(V, compress):
+    """Each node's update is the same code whatever the node block, so
+    the block the VMEM budget picks cannot change a bit of the state."""
+    adj = _adj(random_geometric(V, 0.6, seed=V))
+    betas, omegas = _state(V, 16, 3, seed=7)
+    idx, w, deg = ref.neighbor_lists(adj)
+    outs = [
+        np.asarray(elm_gossip_pallas(
+            betas, omegas, idx, w, deg, 0.05, num_rounds=3, block_v=bv,
+            compress=compress, interpret=True,
+        ))
+        for bv in (1, 4, 8)
+    ]
+    assert not np.array_equal(outs[0], np.asarray(betas))
+    for out in outs[1:]:
+        np.testing.assert_array_equal(out, outs[0])
+
+
+@pytest.mark.parametrize("payload", [False, True])
+def test_block_fit_at_rgg1024(payload):
+    """V = 1024, L = 256, M = 10: the resident gather source alone is the
+    16 MiB default scoped limit, which left one node a grid step; the
+    budget the kernel may request fits blocks of 8 within v5e's 128 MiB
+    VMEM, margin included."""
+    V, L, M = 1024, 256, 10
+    assert fit_block_v(V, L, M, 8, 16 * 2**20, payload=payload) == 1
+    bv = fit_block_v(V, L, M, 8, vmem_budget(), payload=payload)
+    assert bv == 8
+    need = round_vmem_bytes(V, L, M, bv, payload=payload) + 4 * 2**20
+    assert need < 128 * 2**20
 
 
 @pytest.mark.interpret

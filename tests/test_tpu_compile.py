@@ -20,7 +20,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels import autotune, elm_gossip
+from repro.kernels import elm_gossip
 from repro.kernels.elm_predict import (
     elm_predict_pallas,
     elm_predict_stacked_pallas,
@@ -55,10 +55,15 @@ def _gossip_args(V, L, M, d, S=1):
 
 
 def _round(V, L, M, **kw):
-    bv = elm_gossip.fit_block_v(V, L, M, 8, autotune.VMEM_BUDGET)
-    return lambda *a: elm_gossip.elm_gossip_pallas(
-        *a, num_rounds=3, block_v=bv, **kw
-    )
+    """The per-round arm at the node block the dispatcher picks."""
+
+    def call(*a):
+        bv = elm_gossip.fit_block_v(V, L, M, 8, elm_gossip.vmem_budget())
+        return elm_gossip.elm_gossip_pallas(
+            *a, num_rounds=3, block_v=bv, **kw
+        )
+
+    return call
 
 
 # name -> (kernel, [(shape, dtype), ...]) at the chip_smoke.py widths
@@ -109,6 +114,10 @@ CASES = {
     ),
     # the widest per-round state: 64 nodes at L=1024, M=10
     "gossip_round_v64_l1024": (_round(64, 1024, 10), _gossip_args(64, 1024, 10, 12)),
+    # the rgg1024 deployment: V=1024, L=256, M=10, d_max 22 (block of 8)
+    "gossip_round_rgg1024": (
+        _round(1024, 256, 10), _gossip_args(1024, 256, 10, 22)
+    ),
     "gossip_multiround_v16": (
         lambda *a: elm_gossip.elm_gossip_pallas_multiround(*a, num_rounds=5),
         _gossip_args(16, 128, 8, 4, S=2),
