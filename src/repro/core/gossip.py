@@ -31,6 +31,8 @@ import jax
 import numpy as np
 from jax import lax
 
+from repro.core.scopes import phase
+
 Perm = list[tuple[int, int]]
 
 
@@ -137,7 +139,7 @@ def neighbor_laplacian(x, spec: GossipSpec, axis_sizes: dict[str, int]):
 
     One ppermute per edge-permutation per leaf; XLA fuses the subtract/
     accumulate. Unit edge weights (a_ij = 1), matching the paper's
-    simulations.
+    simulations. Its ops are the ``exchange`` phase.
     """
 
     def leaf(v):
@@ -150,7 +152,8 @@ def neighbor_laplacian(x, spec: GossipSpec, axis_sizes: dict[str, int]):
             return jax.numpy.zeros_like(v)
         return acc
 
-    return jax.tree.map(leaf, x)
+    with phase("exchange"):
+        return jax.tree.map(leaf, x)
 
 
 def masked_neighbor_laplacian(
@@ -163,7 +166,8 @@ def masked_neighbor_laplacian(
     (0 = link down, 1 = link up). Every ppermute still executes, so the
     collective schedule (and any compiled program built over it) is
     identical to the fault-free one; a dropped link just contributes
-    zero to the Laplacian. Call inside shard_map.
+    zero to the Laplacian. Call inside shard_map. Its ops are the
+    ``exchange`` phase.
     """
 
     def leaf(v):
@@ -178,7 +182,8 @@ def masked_neighbor_laplacian(
             return jax.numpy.zeros_like(v)
         return acc
 
-    return jax.tree.map(leaf, x)
+    with phase("exchange"):
+        return jax.tree.map(leaf, x)
 
 
 def global_node_index(spec: GossipSpec, axis_sizes: dict[str, int]):

@@ -21,6 +21,9 @@ names.
     reseed    online.reseed_betas (beta_i = Omega_i Q_i)
     woodbury  online.add_chunk / remove_chunk (Algorithm 2 updates)
     rounds    ConsensusEngine.run (eq. (20) rounds, every mixer arm)
+    exchange  gossip.neighbor_laplacian and its masked variant (the
+              ppermute exchange with the mesh neighbors, nested in
+              ``rounds`` on the sharded path)
 """
 
 from __future__ import annotations
@@ -31,7 +34,9 @@ import jax
 from jax.experimental.xla_metadata import set_xla_metadata
 
 PREFIX = "dcelm/"
-PHASES = ("features", "stats", "omega", "reseed", "woodbury", "rounds")
+PHASES = (
+    "features", "stats", "omega", "reseed", "woodbury", "rounds", "exchange",
+)
 ATTRIBUTE = "dcelm_phase"
 
 

@@ -294,6 +294,43 @@ def elm_preact_stats_pallas(
     return P, Q
 
 
+#: rows whose moments one f32 accumulator of ``elm_stats_pallas`` sums.
+#: The kernel adds a node's row tiles into one accumulator in turn; over
+#: 2^20 rows (2,048 tiles) that order erred more than three bf16 passes
+#: on a TPU v5e, so a taller node is summed in blocks of this many rows.
+BLOCK_ROWS = 16384
+
+
+def pairwise_sum(x: jax.Array) -> jax.Array:
+    """``x.sum(0)`` as a balanced tree of adds: each term goes through
+    about log2(len(x)) roundings, not up to len(x) of them."""
+    while x.shape[0] > 1:
+        h = x.shape[0] // 2
+        head = x[:h] + x[h:2 * h]
+        x = jnp.concatenate([head, x[2 * h:]]) if x.shape[0] % 2 else head
+    return x[0]
+
+
+def by_row_blocks(moments, X: jax.Array, T: jax.Array, rows: int):
+    """``moments(X, T)`` of one node, summed over blocks of ``rows``
+    rows: the whole blocks in one batched call, added pairwise, then
+    the rows left over. A node of fewer than two blocks is one call."""
+    N = X.shape[0]
+    blocks = N // rows
+    if blocks < 2:
+        return moments(X, T)
+    head = blocks * rows
+    P, Q = jax.vmap(moments)(
+        X[:head].reshape(blocks, rows, X.shape[1]),
+        T[:head].reshape(blocks, rows, T.shape[1]),
+    )
+    P, Q = pairwise_sum(P), pairwise_sum(Q)
+    if head < N:
+        dP, dQ = moments(X[head:], T[head:])
+        P, Q = P + dP, Q + dQ
+    return P, Q
+
+
 @functools.partial(
     jax.jit,
     static_argnames=(
@@ -317,29 +354,51 @@ def elm_stats_pallas(
     X: (N, D), W: (D, L), b: (L,), T: (N, M) -> P: (L, L) f32,
     Q: (L, M) f32. For activation="rbf" pass W = centers^T (D, L) and
     b = gamma (L,). symmetric=True computes only the upper block
-    triangle of P (~2x fewer MXU flops) and mirrors it.
+    triangle of P (~2x fewer MXU flops) and mirrors it. A node of two
+    ``BLOCK_ROWS`` blocks or more is summed in blocks (``by_row_blocks``)
+    before P is mirrored.
     """
-    N, D = X.shape
+    D = X.shape[1]
     L = W.shape[1]
     M = T.shape[1]
     bl = min(block_l, L)
-    bn = min(block_n, N)
-    # pad to tile multiples; padded X *rows* are masked inside the
-    # kernel (g(0) != 0 in general), padded L/M/D extents are sliced or
-    # contribute exact zeros
-    pN, pL, pD, pM = (-N) % bn, (-L) % bl, (-D) % 128, (-M) % 128
-    if pN or pD:
-        X = jnp.pad(X, ((0, pN), (0, pD)))
+    # padded L/D extents are sliced or contribute exact zeros
+    pL, pD = (-L) % bl, (-D) % 128
     if pL or pD:
         W = jnp.pad(W, ((0, pD), (0, pL)))
     b2 = jnp.pad(b, (0, pL))[None, :].astype(jnp.float32)  # (1, L2), 2D
-    if pN or pM:
-        T = jnp.pad(T, ((0, pN), (0, pM)))
     # feature matmul runs at the feature dtype (bf16 operands, f32
     # acc); the targets keep their own precision — the Q dot promotes
     # h to T's dtype instead of quantizing f32 targets down to bf16
     W = W.astype(X.dtype)
     T = T.astype(jnp.promote_types(X.dtype, T.dtype))
+    call = functools.partial(
+        _stats_call, W=W, b2=b2, activation=activation, block_l=bl,
+        block_n=block_n, interpret=interpret, symmetric=symmetric,
+    )
+    P, Q = by_row_blocks(call, X, T, BLOCK_ROWS)
+    P = P[:L, :L]
+    Q = Q[:L, :M]
+    if symmetric:
+        upper = jnp.triu(P)
+        P = upper + upper.T - jnp.diag(jnp.diag(upper))
+    return P, Q
+
+
+def _stats_call(
+    X, T, *, W, b2, activation, block_l, block_n, interpret, symmetric
+):
+    """The kernel over one node's rows: the padded (L2, L2) P, of which
+    only the upper blocks when symmetric, and the padded (L2, M2) Q."""
+    N = X.shape[0]
+    bl, bn = block_l, min(block_n, N)
+    # padded X *rows* are masked inside the kernel (g(0) != 0 in
+    # general); padded D columns meet W's zero rows
+    pN, pD, pM = (-N) % bn, W.shape[0] - X.shape[1], (-T.shape[1]) % 128
+    if pN or pD:
+        X = jnp.pad(X, ((0, pN), (0, pD)))
+    if pN or pM:
+        T = jnp.pad(T, ((0, pN), (0, pM)))
     N2, L2, M2 = X.shape[0], W.shape[1], T.shape[1]
     grid = (L2 // bl, L2 // bl, N2 // bn)
     kernel = functools.partial(
@@ -347,7 +406,7 @@ def elm_stats_pallas(
         activation=activation, num_rows=N, block_n=bn,
         symmetric=symmetric, operand_dtype=X.dtype,
     )
-    P, Q = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
@@ -370,9 +429,3 @@ def elm_stats_pallas(
         interpret=interpret,
         name="elm_stats_pallas",
     )(X, W, W, b2, b2, T)
-    P = P[:L, :L]
-    Q = Q[:L, :M]
-    if symmetric:
-        upper = jnp.triu(P)
-        P = upper + upper.T - jnp.diag(jnp.diag(upper))
-    return P, Q

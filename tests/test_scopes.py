@@ -5,8 +5,8 @@ op's ``op_name`` metadata (``repro.core.scopes``). These tests compile
 the programs the benchmark's cells run, at toy sizes on the CPU with the
 Pallas kernels in interpret mode, and check where their ops land: the
 stats kernel under ``stats``, the Cholesky under ``omega``, the Woodbury
-update under ``woodbury`` and every round body, on each mixer arm, under
-``rounds``.
+update under ``woodbury``, every round body, on each mixer arm, under
+``rounds``, and the sharded round's ppermutes under ``exchange``.
 """
 
 import contextlib
@@ -85,7 +85,7 @@ def _chunk(eng, gamma, *, remove=False):
 
 def test_phase_names_are_fixed():
     assert scopes.PHASES == (
-        "features", "stats", "omega", "reseed", "woodbury", "rounds"
+        "features", "stats", "omega", "reseed", "woodbury", "rounds", "exchange"
     )
     with pytest.raises(ValueError, match="unknown DC-ELM phase"):
         scopes.phase("solve")
@@ -142,6 +142,40 @@ def test_neighbor_kernel_rounds(interpret):
     # the jitted wrapper is named after the kernel's entry point
     assert any("jit(elm_gossip_pallas" in n for n in names)
     assert not any("jit(<unknown>)" in n for n in names)
+
+
+SHARDED_ROUND = r"""
+import re
+import jax, jax.numpy as jnp, numpy as np
+from jax.sharding import Mesh
+from repro.core import engine, gossip
+mesh = Mesh(np.asarray(jax.devices()), ("data",))
+eng = engine.sharded_dc_elm(mesh, gossip.GossipSpec(("data",), ("ring",)), 0.5)
+if {faulty!r}:
+    eng = engine.with_faults(eng, np.ones((2, 4, 4), np.float32))
+S = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)
+text = jax.jit(lambda x, om: eng.run(x, om, 0.45, 1)[0]).lower(
+    S(4, 16, 3), S(4, 16, 16)).compile().as_text()
+for line in text.splitlines():
+    if " collective-permute(" in line:
+        print("PERMUTE", re.search(r'op_name="([^"]+)"', line).group(1))
+"""
+
+
+@pytest.mark.parametrize("faulty", [False, True], ids=["ring", "masked"])
+def test_sharded_round_exchange(faulty):
+    """One round of the sharded engine on four host devices: both
+    ppermutes of the ring (the masked variant's too) are ``exchange``,
+    nested in ``rounds``."""
+    from tests.conftest import run_py
+
+    proc = run_py(SHARDED_ROUND.format(faulty=faulty), devices=4)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    names = [x.split(" ", 1)[1] for x in proc.stdout.splitlines()
+             if x.startswith("PERMUTE ")]
+    assert len(names) == 2  # +1 and -1 shifts
+    assert {phase(n) for n in names} == {"exchange"}
+    assert all("dcelm/rounds/" in n for n in names)
 
 
 def test_phase_is_in_the_compile_cache_key():

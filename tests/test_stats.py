@@ -7,7 +7,7 @@ import numpy as np
 import pytest
 
 from repro.core import dc_elm, elm, engine, features, online, stats
-from repro.kernels import gram_ops
+from repro.kernels import elm_stats, gram_ops
 from repro.kernels.elm_stats import elm_stats_pallas
 from repro.kernels.elm_stats_ref import elm_stats_scan, hidden_reference
 
@@ -190,6 +190,53 @@ def test_from_raw_chunk_option_and_nonfusable_fallback():
     opaque = stats.from_raw(X, T, OpaqueMap(), chunk=17)
     np.testing.assert_allclose(opaque.P, ref.P, rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(opaque.Q, ref.Q, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("N,calls", [
+    (96, [32]),          # three whole blocks, one batched call
+    (101, [32, 5]),      # and the rows left over
+    (63, [63]),          # under two blocks: one call, as before
+])
+def test_tall_node_moments_sum_row_blocks(N, calls):
+    fmap, X, T = _problem(N, 4, 12, 2, seed=11)
+    W, b, act = stats.fusable_params(fmap)
+    seen = []
+
+    def moments(x, t):
+        seen.append(x.shape[0])
+        return elm_stats_scan(x, W, b, t, activation=act, chunk=16)
+
+    P, Q = elm_stats.by_row_blocks(moments, X, T, 32)
+    assert seen == calls
+    P0, Q0 = gram_ops.local_elm_stats(fmap(X), T)
+    np.testing.assert_allclose(P, P0, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(Q, Q0, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("n", [1, 2, 5, 8])
+def test_pairwise_sum_adds_every_term(n):
+    x = jnp.arange(n * 6, dtype=jnp.float32).reshape(n, 2, 3)
+    np.testing.assert_array_equal(elm_stats.pairwise_sum(x), x.sum(0))
+
+
+@pytest.mark.interpret
+def test_fused_kernel_sums_a_tall_node_in_row_blocks():
+    """Two whole blocks of ``BLOCK_ROWS`` rows and the rest: the sum of
+    the three calls' moments, to the bit (the mirror is exact), and a
+    symmetric P."""
+    B = elm_stats.BLOCK_ROWS
+    fmap, X, T = _problem(2 * B + 5, 3, 16, 2, seed=12)
+    W, b, act = stats.fusable_params(fmap)
+    kw = dict(activation=act, interpret=True, block_l=16, block_n=2048)
+    P, Q = elm_stats_pallas(X, W, b, T, **kw)
+    parts = [elm_stats_pallas(X[s:e], W, b, T[s:e], **kw)
+             for s, e in ((0, B), (B, 2 * B), (2 * B, 2 * B + 5))]
+    np.testing.assert_array_equal(P, (parts[0][0] + parts[1][0]) + parts[2][0])
+    np.testing.assert_array_equal(Q, (parts[0][1] + parts[1][1]) + parts[2][1])
+    np.testing.assert_array_equal(P, P.T)
+    P0, Q0 = gram_ops.local_elm_stats(fmap(X), T)
+    np.testing.assert_allclose(P, P0, rtol=1e-5)
+    np.testing.assert_allclose(Q, Q0, rtol=1e-5, atol=1e-4)
 
 
 # ---------------------------------------------------------------------------

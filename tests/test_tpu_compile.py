@@ -80,6 +80,14 @@ CASES = {
         [((64, 4096, 784), F32), ((784, 1024), F32), ((1024,), F32),
          ((64, 4096, 10), F32)],
     ),
+    # the sharded engine: one node of 2^20 rows a chip, as stream_init's
+    # shard_map runs it (its vmap over the one node the chip holds), in
+    # blocks of elm_stats.BLOCK_ROWS rows
+    "stats_f32_silo": (
+        jax.vmap(elm_stats_pallas, in_axes=(0, None, None, 0)),
+        [((1, 1 << 20, 784), F32), ((784, 1024), F32), ((1024,), F32),
+         ((1, 1 << 20, 10), F32)],
+    ),
     "stats_bf16": (
         elm_stats_pallas,
         [((4096, 784), BF16), ((784, 1024), BF16), ((1024,), F32),
