@@ -20,6 +20,9 @@
   attn.py / attn_ops.py / attn_ref.py   causal/SWA GQA flash attention
   decode_attn.py                        flash-decode (one token vs a
                                         long KV cache, serving hot path)
+  vmem.py                               the VMEM a kernel may request,
+                                        which sizes gossip node blocks
+                                        and picks the stats kernel's arm
   autotune.py                           measured block/chunk autotuner:
                                         roofline-pruned candidate sweep
                                         cached to TUNED_kernels.json;

@@ -43,6 +43,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.vmem import vmem_params
+
 
 def _rup(x: int, m: int) -> int:
     return x + (-x) % m
@@ -174,7 +176,7 @@ def _snapshot(arr, k):
 def round_vmem_bytes(V, L, M, block_v, *, payload=False) -> int:
     """VMEM the per-round arm keeps: the whole gather source (single
     buffered — its block never moves), and double-buffered per-block
-    state, Omega and output tiles. ``_vmem_params`` requests this plus
+    state, Omega and output tiles. ``vmem_params`` requests this plus
     a margin; ``fit_block_v`` holds it to ``vmem_budget()``."""
     Vp, Lp, Mp = _rup(V, block_v), _rup(L, 128), _rup(M, 8)
     state = 4 * Vp * Mp * Lp
@@ -192,36 +194,6 @@ def fit_block_v(V, L, M, block_v, budget, *, payload=False) -> int:
     while bv > 1 and round_vmem_bytes(V, L, M, bv, payload=payload) > budget:
         bv //= 2
     return bv
-
-
-#: VMEM per TensorCore of TPU v5e, the chip this repo targets, for when
-#: the default device is no TPU (source: the "TPU v5 lite" entry of
-#: ``jax.experimental.pallas.tpu.get_tpu_info``, JAX 0.9)
-_V5E_VMEM_BYTES = 128 * 2**20
-
-
-def vmem_budget() -> int:
-    """The VMEM a per-round node block may request: half the core's
-    capacity, the other half left to Mosaic's own scratch and the
-    ``_vmem_params`` margin. Read from the default device where JAX
-    describes it, else v5e's capacity (off TPU the block only sizes
-    interpret-mode runs, whose outputs do not depend on it)."""
-    try:
-        capacity = pltpu.get_tpu_info().vmem_capacity_bytes
-    except ValueError:  # not a TPU device kind
-        capacity = _V5E_VMEM_BYTES
-    return capacity // 2
-
-
-def _vmem_params(nbytes: int):
-    """Raise the scoped VMEM limit to the resident set plus a 4 MiB
-    margin when that passes the 16 MiB default. The default is only a
-    starting point: blocks are sized against ``vmem_budget()``, the
-    most this may ask for."""
-    limit = nbytes + 4 * 2**20
-    if limit <= 16 * 2**20:
-        return None
-    return pltpu.CompilerParams(vmem_limit_bytes=int(limit))
 
 
 # ---------------------------------------------------------------------------
@@ -269,7 +241,7 @@ def elm_gossip_pallas(
         ],
         out_specs=pl.BlockSpec((bv, Mp, Lp), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((Vp, Mp, Lp), jnp.float32),
-        compiler_params=_vmem_params(
+        compiler_params=vmem_params(
             round_vmem_bytes(V, L, M, bv, payload=payload is not None)
         ),
         interpret=interpret,
@@ -328,7 +300,7 @@ def elm_gossip_pallas_multiround(
         out_specs=whole(Vp, Mp, Lp),
         out_shape=jax.ShapeDtypeStruct((Vp, Mp, Lp), jnp.float32),
         scratch_shapes=[pltpu.VMEM((Vp, Mp, Lp), jnp.float32)],
-        compiler_params=_vmem_params(multiround_vmem_bytes(V, L, M, S, d_max)),
+        compiler_params=vmem_params(multiround_vmem_bytes(V, L, M, S, d_max)),
         interpret=interpret,
         name="elm_gossip_pallas_multiround",
     )(ip.reshape(-1), wp.reshape(-1), dg.reshape(-1), scale, bt, om)

@@ -149,8 +149,8 @@ def fused_gossip_rounds(
         from repro.kernels.elm_gossip import (
             fit_block_v,
             multiround_vmem_bytes,
-            vmem_budget,
         )
+        from repro.kernels.vmem import vmem_budget
 
         cfg = _resolve(
             {"block_n": block_v}, tuning,
@@ -208,11 +208,8 @@ def fused_gossip_round(
     if betas.dtype != jnp.float32 or payload.dtype != jnp.float32:
         use = False
     if use:
-        from repro.kernels.elm_gossip import (
-            elm_gossip_pallas,
-            fit_block_v,
-            vmem_budget,
-        )
+        from repro.kernels.elm_gossip import elm_gossip_pallas, fit_block_v
+        from repro.kernels.vmem import vmem_budget
 
         cfg = _resolve(
             {"block_n": block_v}, tuning,

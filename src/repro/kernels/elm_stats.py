@@ -15,11 +15,24 @@ so the (N, L) hidden matrix is **never written to HBM** — the paper's
 replaces the two-pass pipeline (materialize H, then kernels/gram.py)
 for every raw-input entry point; `core/stats.py` is the consumer.
 
-Tiling mirrors gram.py: grid = (L/bl, L/bl, N/bn) with n innermost so
-the (bl, bl) f32 P block stays resident while N streams through. The Q
-block rides the same grid — its index map is constant in (j, n), so it
-stays resident for a whole row-block i and accumulates on the diagonal
-visit (symmetric mode) or at j == 0.
+Two arms, chosen in ``_stats_call`` from shapes alone:
+
+* whole-L (``elm_stats_pallas_whole_l``): grid = (N/bn,). Each step
+  builds the row tile's hidden layer once, (bn, L) wide, and adds P's
+  upper block triangle in a static loop over ``block_l``-wide row
+  blocks, P[i, i:] += H_i^T H[:, i:], then Q += H^T T. W, b, P and Q
+  have constant index maps: fetched once a call, resident throughout.
+* L-blocked (``elm_stats_pallas``): grid = (L/bl, L/bl, N/bn) with n
+  innermost, so a (bl, bl) P block stays resident while N streams
+  through. Each visit rebuilds its hidden tiles; Q's block is constant
+  in (j, n) and accumulates on the diagonal visit (symmetric mode) or
+  at j == 0.
+
+The whole-L arm runs whenever its resident set (``whole_l_vmem_bytes``)
+fits ``vmem.vmem_budget()``, half the core's VMEM (64 MiB on a v5e: to
+L = 2048 at the default tiles); it requests that set plus a margin.
+Wider L falls back to the L-blocked grid. Both add each tile's
+contribution to an element over the same bn rows in the same order.
 
 Dtype policy: operands (X, W, H tiles) may be bf16 — the MXU matmuls
 run with f32 accumulation (`preferred_element_type`), the activation is
@@ -49,10 +62,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-#: Scoped VMEM for the moment kernels. An f32 dot at HIGHEST keeps bf16
-#: splits of its operands in VMEM beside the double-buffered blocks: at
-#: the default tiles and D = 784, the node-batched (vmapped) f32 kernel
-#: needs just over the 16 MiB default when compiled for a TPU v5e.
+from repro.kernels.vmem import vmem_budget, vmem_params
+
+#: Scoped VMEM for the L-blocked moment kernels (the whole-L arm asks
+#: for its own resident set, ``whole_l_vmem_bytes``). An f32 dot at
+#: HIGHEST keeps bf16 splits of its operands in VMEM beside the
+#: double-buffered blocks: at the default tiles and D = 784, the
+#: node-batched (vmapped) f32 kernel needs just over the 16 MiB default
+#: when compiled for a TPU v5e.
 _VMEM_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=32 * 2**20)
 
 
@@ -170,6 +187,48 @@ def _accumulate(tile_i, tile_j, t_ref, p_ref, q_ref, *, i, j, symmetric):
         pl.when(j != 0)(visit(with_q=False, diagonal=False))
 
 
+def _elm_stats_whole_l_kernel(
+    x_ref, w_ref, b_ref, t_ref, p_ref, q_ref,
+    *, activation, num_rows, block_n, block_l, symmetric, operand_dtype,
+):
+    n = pl.program_id(0)
+
+    @pl.when(n == 0)
+    def _init():
+        p_ref[...] = jnp.zeros_like(p_ref)
+        q_ref[...] = jnp.zeros_like(q_ref)
+
+    tile = functools.partial(
+        hidden_tile, x_ref, w_ref, b_ref,
+        activation=activation, rows_in_tile=num_rows - n * block_n,
+        out_dtype=operand_dtype,
+    )
+    _accumulate_whole_l(
+        tile, t_ref, p_ref, q_ref, block_l=block_l, symmetric=symmetric
+    )
+
+
+def _accumulate_whole_l(tile, t_ref, p_ref, q_ref, *, block_l, symmetric):
+    """P[i, i:] += H_i^T H[:, i:] for each ``block_l``-wide row block i
+    (P[i, :] when not symmetric), and Q += H^T T, from one (bn, L)
+    hidden tile built once."""
+    TN = (((0,), (0,)), ((), ()))  # contract rows: H^T @ ·
+    h = tile()
+    for lo in range(0, h.shape[1], block_l):
+        hi, start = lo + block_l, lo if symmetric else 0
+        p_ref[lo:hi, start:] += jax.lax.dot_general(
+            h[:, lo:hi], h[:, start:], TN,
+            precision=mxu_precision(h.dtype),
+            preferred_element_type=jnp.float32,
+        )
+    # f32 targets with bf16 features: promote h rather than quantize T
+    t = t_ref[...]
+    q_ref[...] += jax.lax.dot_general(
+        h.astype(t.dtype), t, TN, precision=mxu_precision(t.dtype),
+        preferred_element_type=jnp.float32,
+    )
+
+
 def preact_tile(z_ref, b_ref, *, activation, rows_in_tile, out_dtype):
     """g(Z_tile + b_blk), rows past `rows_in_tile` masked to 0.
 
@@ -240,9 +299,9 @@ def elm_preact_stats_pallas(
     """(P, Q) = (H^T H, H^T T) with H = g(Z + b) fused in VMEM.
 
     Z: (N, L) assembled preactivation, b: (L,), T: (N, M) -> P: (L, L)
-    f32, Q: (L, M) f32. The grid mirrors ``elm_stats_pallas`` — only
-    the tile producer changes (no feature matmul; Z streams straight
-    from HBM in (bn, bl) tiles). Padded L columns evaluate g(0) != 0
+    f32, Q: (L, M) f32. The grid is ``elm_stats_pallas``'s L-blocked
+    one — only the tile producer changes (no feature matmul; Z streams
+    straight from HBM in (bn, bl) tiles). Padded L columns evaluate g(0) != 0
     but land outside the [:L, :L] slice, exactly like padded W columns
     in the fused pipeline; padded N rows are masked in-kernel.
     """
@@ -385,11 +444,25 @@ def elm_stats_pallas(
     return P, Q
 
 
+def whole_l_vmem_bytes(block_n, D, L, M, dtype) -> int:
+    """VMEM the whole-L arm keeps at padded widths: the X, W, b, T, P
+    and Q blocks, each buffered twice (the constant ones are fetched
+    once all the same), and the (block_n, L) hidden tile in f32 with
+    the three bf16 parts a HIGHEST dot splits it into."""
+    size = jnp.dtype(dtype).itemsize
+    blocks = size * (block_n * D + D * L) + 4 * (
+        8 * L + block_n * M + L * L + L * M
+    )
+    return 2 * blocks + (4 + 3 * 2) * block_n * L
+
+
 def _stats_call(
     X, T, *, W, b2, activation, block_l, block_n, interpret, symmetric
 ):
     """The kernel over one node's rows: the padded (L2, L2) P, of which
-    only the upper blocks when symmetric, and the padded (L2, M2) Q."""
+    only the upper blocks when symmetric, and the padded (L2, M2) Q.
+    The whole-L arm where its resident set fits ``vmem_budget()``, else
+    the L-blocked grid."""
     N = X.shape[0]
     bl, bn = block_l, min(block_n, N)
     # padded X *rows* are masked inside the kernel (g(0) != 0 in
@@ -399,7 +472,36 @@ def _stats_call(
         X = jnp.pad(X, ((0, pN), (0, pD)))
     if pN or pM:
         T = jnp.pad(T, ((0, pN), (0, pM)))
-    N2, L2, M2 = X.shape[0], W.shape[1], T.shape[1]
+    N2, D2, L2, M2 = X.shape[0], X.shape[1], W.shape[1], T.shape[1]
+    out_shape = [
+        jax.ShapeDtypeStruct((L2, L2), jnp.float32),
+        jax.ShapeDtypeStruct((L2, M2), jnp.float32),
+    ]
+    nbytes = whole_l_vmem_bytes(bn, D2, L2, M2, X.dtype)
+    if nbytes <= vmem_budget():
+        kernel = functools.partial(
+            _elm_stats_whole_l_kernel,
+            activation=activation, num_rows=N, block_n=bn, block_l=bl,
+            symmetric=symmetric, operand_dtype=X.dtype,
+        )
+        return pl.pallas_call(
+            kernel,
+            grid=(N2 // bn,),
+            in_specs=[
+                pl.BlockSpec((bn, D2), lambda n: (n, 0)),   # X
+                pl.BlockSpec((D2, L2), lambda n: (0, 0)),   # W
+                pl.BlockSpec((1, L2), lambda n: (0, 0)),    # b
+                pl.BlockSpec((bn, M2), lambda n: (n, 0)),   # T
+            ],
+            out_specs=[
+                pl.BlockSpec((L2, L2), lambda n: (0, 0)),
+                pl.BlockSpec((L2, M2), lambda n: (0, 0)),
+            ],
+            out_shape=out_shape,
+            compiler_params=vmem_params(nbytes),
+            interpret=interpret,
+            name="elm_stats_pallas_whole_l",
+        )(X, W, b2, T)
     grid = (L2 // bl, L2 // bl, N2 // bn)
     kernel = functools.partial(
         _elm_stats_kernel,
@@ -421,10 +523,7 @@ def _stats_call(
             pl.BlockSpec((bl, bl), lambda i, j, n: (i, j)),
             pl.BlockSpec((bl, M2), lambda i, j, n: (i, 0)),
         ],
-        out_shape=[
-            jax.ShapeDtypeStruct((L2, L2), jnp.float32),
-            jax.ShapeDtypeStruct((L2, M2), jnp.float32),
-        ],
+        out_shape=out_shape,
         compiler_params=_VMEM_PARAMS,
         interpret=interpret,
         name="elm_stats_pallas",

@@ -30,8 +30,8 @@ from repro.kernels.elm_gossip import (
     fit_block_v,
     multiround_vmem_bytes,
     round_vmem_bytes,
-    vmem_budget,
 )
+from repro.kernels.vmem import vmem_budget
 
 TOL = dict(rtol=2e-5, atol=2e-5)
 

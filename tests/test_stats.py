@@ -7,7 +7,7 @@ import numpy as np
 import pytest
 
 from repro.core import dc_elm, elm, engine, features, online, stats
-from repro.kernels import elm_stats, gram_ops
+from repro.kernels import elm_stats, gram_ops, vmem
 from repro.kernels.elm_stats import elm_stats_pallas
 from repro.kernels.elm_stats_ref import elm_stats_scan, hidden_reference
 
@@ -23,13 +23,33 @@ def _problem(N, D, L, M, activation="sigmoid", dtype=jnp.float32, seed=0):
 
 
 # ---------------------------------------------------------------------------
-# Fused kernel vs the materialize-then-gram oracle
+# Fused kernel vs the materialize-then-gram oracle, in both arms
 # ---------------------------------------------------------------------------
+
+
+def _blocked_arm(monkeypatch):
+    """Send ``elm_stats_pallas`` to its L-blocked arm: a VMEM budget no
+    whole-L resident set fits. The arm is fixed when a shape is first
+    traced, so jit caches are cleared on the way in and out."""
+    monkeypatch.setattr(elm_stats, "vmem_budget", lambda: 0)
+    jax.clear_caches()
+
+
+@pytest.fixture(params=["whole_l", "blocked"])
+def arm(request, monkeypatch):
+    """The stats kernel's arm a test runs: whole-L (every shape here
+    fits the real budget) or L-blocked."""
+    if request.param == "blocked":
+        _blocked_arm(monkeypatch)
+        yield request.param
+        jax.clear_caches()
+    else:
+        yield request.param
 
 
 @pytest.mark.interpret
 @pytest.mark.parametrize("activation", ALL_ACTIVATIONS)
-def test_fused_kernel_matches_oracle_all_activations(activation):
+def test_fused_kernel_matches_oracle_all_activations(arm, activation):
     fmap, X, T = _problem(100, 5, 33, 3, activation)
     W, b, act = stats.fusable_params(fmap)
     P1, Q1 = elm_stats_pallas(
@@ -45,7 +65,7 @@ def test_fused_kernel_matches_oracle_all_activations(activation):
     "N,D,L,M", [(64, 4, 32, 2), (300, 7, 100, 1), (33, 3, 7, 5),
                 (128, 16, 64, 8)]
 )
-def test_fused_kernel_shape_sweep_ragged(N, D, L, M):
+def test_fused_kernel_shape_sweep_ragged(arm, N, D, L, M):
     """Ragged N/L/M tails must mask, not pollute (g(0) != 0!)."""
     fmap, X, T = _problem(N, D, L, M)
     W, b, act = stats.fusable_params(fmap)
@@ -60,7 +80,7 @@ def test_fused_kernel_shape_sweep_ragged(N, D, L, M):
 
 @pytest.mark.interpret
 @pytest.mark.parametrize("activation", ["sigmoid", "rbf"])
-def test_fused_kernel_bf16_operands(activation):
+def test_fused_kernel_bf16_operands(arm, activation):
     fmap, X, T = _problem(128, 6, 40, 2, activation)
     W, b, act = stats.fusable_params(fmap)
     Xb, Tb = X.astype(jnp.bfloat16), T.astype(jnp.bfloat16)
@@ -78,7 +98,7 @@ def test_fused_kernel_bf16_operands(activation):
 
 
 @pytest.mark.interpret
-def test_fused_kernel_keeps_f32_target_precision():
+def test_fused_kernel_keeps_f32_target_precision(arm):
     """bf16 features + f32 targets with a large offset: the kernel must
     not quantize T down to bf16 — pinned against the scan path, which
     keeps T f32."""
@@ -98,7 +118,7 @@ def test_fused_kernel_keeps_f32_target_precision():
 
 
 @pytest.mark.interpret
-def test_fused_kernel_symmetric_matches_full():
+def test_fused_kernel_symmetric_matches_full(arm):
     fmap, X, T = _problem(96, 5, 48, 2)
     W, b, act = stats.fusable_params(fmap)
     kw = dict(activation=act, interpret=True, block_l=16, block_n=32)
@@ -106,6 +126,82 @@ def test_fused_kernel_symmetric_matches_full():
     P_full, Q_full = elm_stats_pallas(X, W, b, T, symmetric=False, **kw)
     np.testing.assert_allclose(P_sym, P_full, rtol=1e-6, atol=1e-5)
     np.testing.assert_array_equal(Q_sym, Q_full)
+
+
+@pytest.mark.interpret
+@pytest.mark.parametrize("activation,N,D,L,M,symmetric", [
+    ("sigmoid", 100, 5, 33, 3, True),
+    ("rbf", 100, 5, 33, 3, True),
+    ("tanh", 300, 7, 100, 1, True),
+    ("relu", 128, 16, 64, 8, False),
+])
+def test_stats_arms_agree(monkeypatch, activation, N, D, L, M, symmetric):
+    """The whole-L arm against the L-blocked one: each element sums the
+    same rows in the same order, so the two differ only by the f32
+    rounding of differently shaped CPU dots (none at some shapes)."""
+    fmap, X, T = _problem(N, D, L, M, activation)
+    W, b, act = stats.fusable_params(fmap)
+    kw = dict(activation=act, interpret=True, block_l=16, block_n=32,
+              symmetric=symmetric)
+    P1, Q1 = elm_stats_pallas(X, W, b, T, **kw)
+    _blocked_arm(monkeypatch)
+    try:
+        P2, Q2 = elm_stats_pallas(X, W, b, T, **kw)
+    finally:
+        jax.clear_caches()
+    np.testing.assert_allclose(P1, P2, rtol=0, atol=1e-6 * np.abs(P2).max())
+    np.testing.assert_allclose(Q1, Q2, rtol=0, atol=1e-6 * np.abs(Q2).max())
+
+
+def _stats_kernel_names(fn, *args):
+    """Names of the Pallas calls ``fn`` makes at these abstract shapes
+    (traced, not compiled)."""
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield str(eqn.params["name"])
+            for v in eqn.params.values():
+                inner = getattr(v, "jaxpr", v)
+                if hasattr(inner, "eqns"):
+                    yield from walk(inner)
+
+    shapes = [jax.ShapeDtypeStruct(shape, jnp.float32) for shape in args]
+    return set(walk(jax.jit(fn).trace(*shapes).jaxpr.jaxpr))
+
+
+_nodes = jax.vmap(elm_stats_pallas, in_axes=(0, None, None, 0))
+
+#: the stats kernel's shapes in each benchmark cell, and L = 2048
+ARM_SHAPES = {
+    "mnist64": (_nodes, (64, 16384, 784), (784, 1024), (1024,),
+                (64, 16384, 10)),
+    "mnist8m-silo4": (_nodes, (1, 1 << 20, 784), (784, 1024), (1024,),
+                      (1, 1 << 20, 10)),
+    "rgg1024": (_nodes, (1024, 256, 784), (784, 256), (256,),
+                (1024, 256, 10)),
+    "wide": (elm_stats_pallas, (32768, 784), (784, 2048), (2048,),
+             (32768, 10)),
+}
+
+
+@pytest.mark.parametrize("small_budget", [False, True])
+@pytest.mark.parametrize("shape", sorted(ARM_SHAPES))
+def test_stats_arm_chooser(monkeypatch, shape, small_budget):
+    """Under v5e's budget every cell's shape, and L = 2048, takes the
+    whole-L arm; a budget too small for its resident set takes the
+    L-blocked one."""
+    budget = vmem._V5E_VMEM_BYTES // 2
+    if small_budget:
+        budget = 16 * 2**20 if shape == "wide" else 2**20
+    monkeypatch.setattr(elm_stats, "vmem_budget", lambda: budget)
+    jax.clear_caches()
+    try:
+        names = _stats_kernel_names(*ARM_SHAPES[shape])
+    finally:
+        jax.clear_caches()
+    arm = "elm_stats_pallas" if small_budget else "elm_stats_pallas_whole_l"
+    assert names == {arm}
 
 
 def test_streaming_scan_matches_oracle():
@@ -220,7 +316,7 @@ def test_pairwise_sum_adds_every_term(n):
 
 
 @pytest.mark.interpret
-def test_fused_kernel_sums_a_tall_node_in_row_blocks():
+def test_fused_kernel_sums_a_tall_node_in_row_blocks(arm):
     """Two whole blocks of ``BLOCK_ROWS`` rows and the rest: the sum of
     the three calls' moments, to the bit (the mirror is exact), and a
     symmetric P."""

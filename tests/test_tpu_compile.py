@@ -26,6 +26,7 @@ from repro.kernels.elm_predict import (
     elm_predict_stacked_pallas,
 )
 from repro.kernels.elm_stats import elm_preact_stats_pallas, elm_stats_pallas
+from repro.kernels.vmem import vmem_budget
 
 F32, BF16, I32 = jnp.float32, jnp.bfloat16, jnp.int32
 
@@ -58,7 +59,7 @@ def _round(V, L, M, **kw):
     """The per-round arm at the node block the dispatcher picks."""
 
     def call(*a):
-        bv = elm_gossip.fit_block_v(V, L, M, 8, elm_gossip.vmem_budget())
+        bv = elm_gossip.fit_block_v(V, L, M, 8, vmem_budget())
         return elm_gossip.elm_gossip_pallas(
             *a, num_rounds=3, block_v=bv, **kw
         )
@@ -79,6 +80,12 @@ CASES = {
         jax.vmap(elm_stats_pallas, in_axes=(0, None, None, 0)),
         [((64, 4096, 784), F32), ((784, 1024), F32), ((1024,), F32),
          ((64, 4096, 10), F32)],
+    ),
+    # mnist64.learn's own shape: 64 nodes of 16,384 rows, L = 1024
+    "stats_f32_mnist64": (
+        jax.vmap(elm_stats_pallas, in_axes=(0, None, None, 0)),
+        [((64, 16384, 784), F32), ((784, 1024), F32), ((1024,), F32),
+         ((64, 16384, 10), F32)],
     ),
     # the sharded engine: one node of 2^20 rows a chip, as stream_init's
     # shard_map runs it (its vmap over the one node the chip holds), in
@@ -145,9 +152,12 @@ def test_kernel_compiles_for_v5e(one_chip, name):
 
 
 #: the instruction name of each kernel's launches, which a device trace
-#: shows (bench/metrics/stats_roofline.learn.py matches "elm_stats_pallas")
+#: shows (bench/metrics/stats_roofline.learn.py matches "elm_stats_pallas";
+#: the stats kernel's whole-L arm adds "_whole_l")
 KERNEL_NAMES = {
     "stats_f32_nodes64": "elm_stats_pallas",
+    "stats_f32_mnist64": "elm_stats_pallas_whole_l",
+    "stats_f32_silo": "elm_stats_pallas_whole_l",
     "preact_stats_f32": "elm_preact_stats_pallas",
     "predict_f32": "elm_predict_pallas",
     "predict_stacked_T64": "elm_predict_stacked_pallas",
