@@ -1,12 +1,17 @@
-"""Reproductions of the paper's experiments (Figs. 3, 4, 7).
+"""Reproductions of the paper's accuracy experiments (Figs. 3, 4, 7).
 
-Each function returns (rows, summary) where rows are CSV-able tuples
-(name, us_per_call, derived). Numbers land in EXPERIMENTS.md.
+Each function returns (rows, summary); a row is (name, derived), with
+the figure's numbers in derived. The runs are float64, as the paper's
+MATLAB runs were: they invert ill-conditioned Gram matrices.
+
+Run:  PYTHONPATH=src python examples/paper_figs.py [--fig 3,4,7] [--fast]
+(Fig. 4 takes about 15 s; setting (a) diverges on purpose, its gamma
+is above 1/d_max; (b) and (c) end within 0.01 of the centralized beta.)
 """
 
 from __future__ import annotations
 
-import time
+import argparse
 
 import jax
 import jax.numpy as jnp
@@ -16,15 +21,6 @@ from repro.core import consensus, dc_elm, elm
 from repro.data.partition import partition_equal
 from repro.data.sinc import make_sinc_dataset
 from repro.data.synthetic_mnist import make_mnist36_dataset
-
-
-def _timeit(fn, *args, repeats=3):
-    fn(*args)  # compile
-    t0 = time.perf_counter()
-    for _ in range(repeats):
-        out = fn(*args)
-    jax.block_until_ready(out)
-    return (time.perf_counter() - t0) / repeats * 1e6  # us
 
 
 # ---------------------------------------------------------------------------
@@ -48,7 +44,7 @@ def fig3_centralized_sinc(trials: int = 10):
             )
             mses.append(float(elm.mse(model, Xt, Yt)))
         mse, dev = float(np.mean(mses)), float(np.std(mses))
-        rows.append((f"fig3/sinc_centralized_L{L}", 0.0,
+        rows.append((f"fig3/sinc_centralized_L{L}",
                      f"mse={mse:.5f};dev={dev:.5f}"))
     return rows, {"Ls": Ls}
 
@@ -90,7 +86,7 @@ def fig4_dcelm_sinc(iters: int = 300):
         r_d0, r_dk = float(risks[0]), float(risks[-1])
         dist = float(dc_elm.distance_to(final.betas, beta_c))
         rows.append((
-            f"fig4{tag}/C{C:g}_gamma{gamma:.3f}", 0.0,
+            f"fig4{tag}/C{C:g}_gamma{gamma:.3f}",
             f"Rc={r_c:.4f};Rd0={r_d0:.4f};Rdk={r_dk:.4f};dist={dist:.4f}",
         ))
     return rows, {}
@@ -110,7 +106,7 @@ def fig7_mnist(iters: int = 1500):
     # centralized reference (paper: 0.8989 for V=25 setup, 0.9200 for V=100)
     cent = elm.train_centralized(jax.random.key(0), X, T, num_features=L, C=C)
     acc_c = float(elm.accuracy(cent(Xt), Tt))
-    rows.append(("fig7/centralized", 0.0, f"acc={acc_c:.4f}"))
+    rows.append(("fig7/centralized", f"acc={acc_c:.4f}"))
     for V, gamma, radius, seed in [(25, 0.076, 0.35, 1), (100, 0.038, 0.2, 2)]:
         g = consensus.random_geometric(V, radius, seed=seed)
         Xn, Tn = partition_equal(np.asarray(X), np.asarray(T), V)
@@ -122,9 +118,34 @@ def fig7_mnist(iters: int = 1500):
             state, g, gamma, C, iters, trace_fn=trace_fn
         )
         rows.append((
-            f"fig7/V{V}", 0.0,
+            f"fig7/V{V}",
             f"err0={float(errs[0]):.4f};errK={float(errs[-1]):.4f};"
             f"acc={1-float(errs[-1]):.4f};lambda2={g.algebraic_connectivity:.4f};"
             f"dmax={g.d_max:.0f};acc_centralized={acc_c:.4f}",
         ))
     return rows, {}
+
+
+def main() -> None:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--fig", default="3,4,7", help="comma-separated subset")
+    ap.add_argument(
+        "--fast", action="store_true", help="3 Fig. 3 trials, 300 Fig. 7 rounds"
+    )
+    args = ap.parse_args()
+    jax.config.update("jax_enable_x64", True)
+    figs = {
+        "3": (fig3_centralized_sinc, {"trials": 3} if args.fast else {}),
+        "4": (fig4_dcelm_sinc, {}),
+        "7": (fig7_mnist, {"iters": 300} if args.fast else {}),
+    }
+    print("name,derived")
+    for name in args.fig.split(","):
+        fn, kw = figs[name]
+        rows, _ = fn(**kw)
+        for r in rows:
+            print(",".join(r))
+
+
+if __name__ == "__main__":
+    main()

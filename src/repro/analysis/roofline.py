@@ -125,11 +125,9 @@ def gossip_round_terms(
     fused kernel keeps in VMEM (reported separately — it only hits HBM
     when an unfused path materializes the gathered tiles).
 
-    Used for relative ranking (candidate pruning in
-    ``kernels/autotune.py`` op="gossip", the dense-vs-neighbor arm
-    choice in ``kernels/elm_gossip_ops.py``, and the
-    ``benchmarks/micro.py --profile consensus`` rows) — the absolute
-    constants cancel out of those comparisons.
+    Used for relative ranking (the dense-vs-neighbor arm choice in
+    ``kernels/elm_gossip_ops.py``) — the absolute constants cancel out
+    of that comparison.
     """
     fanin = V if dense else d_max
     flops = 2.0 * V * fanin * L * M + 2.0 * V * L * L * M

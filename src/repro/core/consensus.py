@@ -8,8 +8,8 @@ D = diag(d_i), Laplacian Lap = D - A. Connectivity <=> lambda_2(Lap) > 0
 
 Two families of graphs:
   * "simulation" graphs — anything, incl. the paper's random geometric
-    graphs (Fig. 6); used by the vmap-simulated DC-ELM and fidelity
-    benchmarks.
+    graphs (Fig. 6); used by the vmap-simulated DC-ELM and the paper's
+    fidelity experiments (examples/paper_figs.py).
   * "ICI-realizable" graphs — ring / 2-D torus / hypercube / complete —
     whose edge sets decompose into a handful of device permutations, so
     the sharded path lowers to jax.lax.ppermute schedules (see

@@ -20,23 +20,24 @@
   attn.py / attn_ops.py / attn_ref.py   causal/SWA GQA flash attention
   decode_attn.py                        flash-decode (one token vs a
                                         long KV cache, serving hot path)
+  elm_gossip.py / _ops.py / _ref.py     fused eq. (20) gossip round
+                                        over padded neighbor lists;
+                                        feeds core/mixers.NeighborMixer
   vmem.py                               the VMEM a kernel may request,
                                         which sizes gossip node blocks
                                         and picks the stats kernel's arm
-  autotune.py                           measured block/chunk autotuner:
-                                        roofline-pruned candidate sweep
-                                        cached to TUNED_kernels.json;
-                                        the elm_* ops wrappers consult
-                                        it by default (tuning="cached")
+  dispatch.py                           the one dispatch policy of the
+                                        *_ops.py wrappers
 
 Each kernel is a pl.pallas_call with explicit BlockSpec VMEM tiling,
 validated against its pure-jnp oracle in interpret mode (tests/).
-ops.py wrappers dispatch kernel-on-TPU / oracle-elsewhere.
+The *_ops.py wrappers dispatch kernel-on-TPU / fallback-elsewhere
+through dispatch.py; block sizes are the kernels' defaults or derived
+from the VMEM budget (vmem.py).
 """
 
 from repro.kernels import (  # noqa: F401
     attn_ops,
-    autotune,
     elm_predict_ops,
     elm_stats_ops,
     gram_ops,

@@ -8,22 +8,17 @@ validation against the oracle.
 
 from __future__ import annotations
 
-import jax
-
 from repro.kernels.attn_ref import attention_reference
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+from repro.kernels.dispatch import on_tpu
 
 
 def causal_attention(q, k, v, *, softcap: float = 0.0, use_kernel=None, **kw):
-    use = _on_tpu() if use_kernel is None else use_kernel
+    use = on_tpu() if use_kernel is None else use_kernel
     if use:
         from repro.kernels.attn import flash_attention_pallas
 
         return flash_attention_pallas(
-            q, k, v, softcap=softcap, interpret=not _on_tpu(), **kw
+            q, k, v, softcap=softcap, interpret=not on_tpu(), **kw
         )
     return attention_reference(q, k, v, softcap=softcap)
 
@@ -33,13 +28,13 @@ def decode_attention(
     use_kernel=None, **kw,
 ):
     """Single-token attention over a KV cache (flash-decode on TPU)."""
-    use = _on_tpu() if use_kernel is None else use_kernel
+    use = on_tpu() if use_kernel is None else use_kernel
     if use:
         from repro.kernels.decode_attn import flash_decode_pallas
 
         return flash_decode_pallas(
             q, cache_k, cache_v, pos, window=window, softcap=softcap,
-            interpret=not _on_tpu(), **kw,
+            interpret=not on_tpu(), **kw,
         )
     from repro.models.attention import decode_attend
 

@@ -9,11 +9,9 @@ arm the same way the stats/predict planes do:
 * everywhere else: the jitted neighbor-list scan fallback
   (``elm_gossip_ref.elm_gossip_scan``), chunked over neighbor slots.
 
-Block knobs resolve through ``kernels/autotune.py`` at
-``tuning="cached"`` (op="gossip"; the point maps V -> N and
-d_max -> D, so the cache key carries ``V, d_max, L, M, dtype``
-exactly like the other planes carry their dims). Explicit ``chunk=``
-/ ``block_v=`` kwargs always win.
+Block knobs: the Pallas arm's node block ``block_v`` (default
+``GOSSIP_BLOCK_V``, shrunk by ``fit_block_v`` to the VMEM budget) and
+the scan's neighbor-slot ``chunk`` (default ``GOSSIP_SCAN_CHUNK``).
 
 ``prefers_dense`` is the degenerate-graph escape hatch: on dense
 graphs (d_max ~ V — complete topologies, or any graph at very small
@@ -33,12 +31,19 @@ import jax
 import jax.numpy as jnp
 
 from repro.analysis.roofline import gossip_round_terms
-from repro.kernels import autotune
-from repro.kernels.elm_stats_ops import force_interpret
+from repro.kernels.dispatch import on_tpu, wants_kernel
 from repro.kernels.elm_gossip_ref import (
     elm_gossip_scan,
     gossip_round_payload,
 )
+
+#: the Pallas arm's node block before ``fit_block_v`` shrinks it to the
+#: VMEM budget (``elm_gossip_pallas``'s own default)
+GOSSIP_BLOCK_V = 8
+
+#: neighbor slots the scan fallback gathers a step (passed explicitly:
+#: ``elm_gossip_scan``'s ``chunk=None`` gathers every slot at once)
+GOSSIP_SCAN_CHUNK = 8
 
 #: modeled dense-round slack on TPU: the neighbor arm must beat the
 #: dense matmul round by this factor before it is preferred (gathers
@@ -47,14 +52,11 @@ DENSE_SLACK = 1.25
 
 #: off-TPU slack: XLA:CPU lowers the dense round to BLAS GEMMs
 #: running near peak while the neighbor gather+contract runs ~4-5x
-#: below it (measured on the benchmarks/consensus_bench.py grid), so
-#: the dense arm's zero-edge MACs only lose once the modeled compute
-#: ratio clears that efficiency gap
+#: below it (measured on XLA:CPU over hypercube, torus and random
+#: geometric graphs when the gossip kernel came in), so the dense arm's
+#: zero-edge MACs only lose once the modeled compute ratio clears that
+#: efficiency gap
 DENSE_SLACK_OFF_TPU = 5.0
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def prefers_dense(
@@ -77,7 +79,7 @@ def prefers_dense(
     modeled ratio must clear the measured efficiency gap first).
     """
     if slack is None:
-        slack = DENSE_SLACK if _on_tpu() else DENSE_SLACK_OFF_TPU
+        slack = DENSE_SLACK if on_tpu() else DENSE_SLACK_OFF_TPU
     tn = gossip_round_terms(V, d_max, L, M)["t_compute"]
     td = gossip_round_terms(V, d_max, L, M, dense=True)["t_compute"]
     return td <= slack * tn
@@ -116,18 +118,9 @@ def _pallas_jit(multiround: bool):
     )
 
 
-def _resolve(kw, tuning, *, V, d_max, L, M, dtype, impl):
-    cfg = autotune.resolve_config(
-        kw, tuning, op="gossip", impl=impl,
-        N=V, D=d_max, L=L, M=M, dtype=dtype,
-    )
-    return cfg
-
-
 def fused_gossip_rounds(
     betas, omegas, idx, w, deg, scale, *, num_rounds, compress=None,
-    use_kernel=None, tuning="cached", chunk=None, block_v=None,
-    interpret=None,
+    use_kernel=None, chunk=None, block_v=None, interpret=None,
 ):
     """num_rounds fused eq. (20) rounds over padded neighbor lists.
 
@@ -137,10 +130,8 @@ def fused_gossip_rounds(
     """
     V, L, M = betas.shape
     S, _, d_max = idx.shape
-    use = (_on_tpu() or force_interpret()) if use_kernel is None else use_kernel
-    if betas.dtype != jnp.float32:
-        use = False  # the kernel accumulates/stores f32 only
-    if use:
+    # the kernel accumulates/stores f32 only
+    if wants_kernel(use_kernel) and betas.dtype == jnp.float32:
         if chunk is not None:
             raise ValueError(
                 "chunk= is the scan fallback's knob; the Pallas arm "
@@ -150,25 +141,15 @@ def fused_gossip_rounds(
             fit_block_v,
             multiround_vmem_bytes,
         )
-        from repro.kernels.vmem import vmem_budget
+        from repro.kernels.vmem import DEFAULT_SCOPED_VMEM, vmem_budget
 
-        cfg = _resolve(
-            {"block_n": block_v}, tuning,
-            V=V, d_max=d_max, L=L, M=M, dtype=betas.dtype, impl="pallas",
-        )
-        bv = cfg.get("block_n") or autotune.DEFAULTS[
-            ("gossip", "pallas")
-        ]["block_n"]
-        bv = fit_block_v(V, L, M, bv, vmem_budget())
-        interp = (not _on_tpu()) if interpret is None else interpret
-        if (
-            multiround_vmem_bytes(V, L, M, S, d_max)
-            <= autotune.VMEM_BUDGET
-        ):
+        interp = (not on_tpu()) if interpret is None else interpret
+        if multiround_vmem_bytes(V, L, M, S, d_max) <= DEFAULT_SCOPED_VMEM:
             return _pallas_jit(True)(
                 betas, omegas, idx, w, deg, scale, num_rounds=num_rounds,
                 compress=compress, interpret=interp,
             )
+        bv = fit_block_v(V, L, M, block_v or GOSSIP_BLOCK_V, vmem_budget())
         return _pallas_jit(False)(
             betas, omegas, idx, w, deg, scale, num_rounds=num_rounds,
             compress=compress, block_v=int(bv), interpret=interp,
@@ -178,21 +159,16 @@ def fused_gossip_rounds(
             "block_v= is the Pallas arm's knob; the scan fallback "
             "takes chunk="
         )
-    cfg = _resolve(
-        {"chunk": chunk}, tuning,
-        V=V, d_max=d_max, L=L, M=M, dtype=betas.dtype, impl="scan",
-    )
-    c = cfg.get("chunk") or autotune.DEFAULTS[("gossip", "scan")]["chunk"]
     return _scan_jit(
         betas, omegas, idx, w, deg, scale,
-        num_rounds=num_rounds, compress=compress, chunk=int(c),
+        num_rounds=num_rounds, compress=compress,
+        chunk=int(chunk or GOSSIP_SCAN_CHUNK),
     )
 
 
 def fused_gossip_round(
     betas, payload, omegas, idx_k, w_k, deg_k, scale, *,
-    use_kernel=None, tuning="cached", chunk=None, block_v=None,
-    interpret=None,
+    use_kernel=None, chunk=None, block_v=None, interpret=None,
 ):
     """One fused round over an explicitly encoded payload.
 
@@ -203,34 +179,24 @@ def fused_gossip_round(
     (V, d_max) — one already-selected snapshot; deg_k: (V,).
     """
     V, L, M = betas.shape
-    d_max = idx_k.shape[-1]
-    use = (_on_tpu() or force_interpret()) if use_kernel is None else use_kernel
-    if betas.dtype != jnp.float32 or payload.dtype != jnp.float32:
-        use = False
-    if use:
+    if (
+        wants_kernel(use_kernel)
+        and betas.dtype == jnp.float32
+        and payload.dtype == jnp.float32
+    ):
         from repro.kernels.elm_gossip import elm_gossip_pallas, fit_block_v
         from repro.kernels.vmem import vmem_budget
 
-        cfg = _resolve(
-            {"block_n": block_v}, tuning,
-            V=V, d_max=d_max, L=L, M=M, dtype=betas.dtype, impl="pallas",
+        bv = fit_block_v(
+            V, L, M, block_v or GOSSIP_BLOCK_V, vmem_budget(), payload=True
         )
-        bv = cfg.get("block_n") or autotune.DEFAULTS[
-            ("gossip", "pallas")
-        ]["block_n"]
-        bv = fit_block_v(V, L, M, bv, vmem_budget(), payload=True)
-        interp = (not _on_tpu()) if interpret is None else interpret
+        interp = (not on_tpu()) if interpret is None else interpret
         return elm_gossip_pallas(
             betas, omegas, idx_k[None], w_k[None], deg_k[None], scale,
             num_rounds=1, payload=payload, block_v=int(bv),
             interpret=interp,
         )
-    cfg = _resolve(
-        {"chunk": chunk}, tuning,
-        V=V, d_max=d_max, L=L, M=M, dtype=betas.dtype, impl="scan",
-    )
-    c = cfg.get("chunk") or autotune.DEFAULTS[("gossip", "scan")]["chunk"]
     return _round_payload_jit(
         betas, payload, omegas, idx_k, w_k, deg_k, scale,
-        chunk=int(c),
+        chunk=int(chunk or GOSSIP_SCAN_CHUNK),
     )

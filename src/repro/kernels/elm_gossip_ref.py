@@ -20,8 +20,8 @@ padded CSR-style neighbor list: per node, ``d_max`` neighbor slots of
 * ``elm_gossip_scan`` — the jitted off-TPU fallback: ``lax.scan`` over
   rounds, the Laplacian accumulated over neighbor-slot *chunks* so the
   gathered ``(V, chunk, L, M)`` tile — not the full ``(V, d_max, L,
-  M)`` gather — bounds peak memory. ``chunk`` is the knob
-  ``kernels/autotune.py`` sweeps for ``op="gossip"``.
+  M)`` gather — bounds peak memory (``elm_gossip_ops`` passes
+  ``chunk=GOSSIP_SCAN_CHUNK`` unless its caller sets one).
 
 Payload semantics match the mixers: ``compress="bf16"`` rounds each
 element of the gossiped payload to bf16 before the Laplacian is formed

@@ -1,6 +1,6 @@
 """Dispatching wrapper for the fused predict kernel.
 
-Backend policy (mirrors elm_stats_ops):
+Backend policy (``kernels/dispatch.py``):
   * TPU              -> the Pallas kernel (H never touches HBM)
   * use_kernel=True elsewhere -> the kernel in interpret mode
     (correctness path for tests; slow)
@@ -13,14 +13,7 @@ the scan's ``chunk`` (rows resident per streaming step); ``block_l``
 has no scan equivalent (the scan computes all L hidden columns per
 chunk) and a non-None value raises instead of being silently dropped.
 Passing both ``block_n`` and ``chunk`` to the scan path is a conflict
-and raises. The shared mapper is ``elm_stats_ops.scan_kwargs``.
-
-Tuning policy (kernels/autotune.py): ``tuning="cached"`` (default)
-consults the measured-winner cache (TUNED_kernels.json) for this
-problem point and backend — explicit block kwargs always win, and a
-cache miss keeps the hard-coded defaults, so cold-start behavior is
-unchanged. ``tuning="off"`` never consults; ``tuning={...}`` applies
-an explicit config dict.
+and raises (``dispatch.scan_kwargs``).
 
 ``predict_map`` is the FeatureMap-level entry point every prediction
 consumer routes through (``ELM.__call__``, ``dc_elm.node_predict``,
@@ -31,57 +24,43 @@ non-fusable maps (frozen deep backbones) materialize H for the call.
 ``predict_stacked`` is the multi-tenant twin: rows carry tenant ids
 into a stacked (T, L, M) beta tensor, the shared hidden tile is
 computed once per row and contracted against per-row gathered beta
-tiles (``op="stacked"`` in the tuned cache; its scan fallback is a
-jitted gather-then-contract over row chunks). One launch serves every
-tenant in the batch — ``serving.elm_server`` in multi-tenant mode is
-the request-level consumer.
+tiles (its scan fallback is a jitted gather-then-contract over row
+chunks). One launch serves every tenant in the batch —
+``serving.elm_server`` in multi-tenant mode is the request-level
+consumer.
 """
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
-from repro.kernels import autotune
-from repro.kernels.elm_stats_ops import force_interpret, scan_kwargs
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+from repro.kernels.dispatch import (
+    on_tpu,
+    pallas_kwargs,
+    scan_kwargs,
+    wants_kernel,
+)
 
 
 def fused_predict(
     X, W, b, beta, *, activation: str = "sigmoid",
-    use_kernel: bool | None = None, tuning="cached", **kw,
+    use_kernel: bool | None = None, **kw,
 ):
     """Y = g(X W + b) @ beta without materializing H.
 
     For activation="rbf" pass W = centers^T and b = gamma. Returns the
     oracle's result dtype (the promoted X/W/beta chain) with f32
-    accumulation inside. ``tuning`` selects the block-knob policy (see
-    module docstring).
+    accumulation inside.
     """
     from repro.kernels.elm_predict_ref import predict_dtype
 
     out_dtype = predict_dtype(X, W, beta)
-    use = (_on_tpu() or force_interpret()) if use_kernel is None else use_kernel
-    kw = autotune.resolve_config(
-        kw, tuning, op="predict", impl="pallas" if use else "scan",
-        N=X.shape[0], D=X.shape[1], L=W.shape[1], M=beta.shape[1],
-        dtype=X.dtype,
-    )
-    if use:
+    if wants_kernel(use_kernel):
         from repro.kernels.elm_predict import elm_predict_pallas
 
-        if kw.get("chunk") is not None:
-            raise ValueError(
-                "chunk is the scan-fallback knob; the Pallas kernel "
-                "takes block_n/block_l"
-            )
-        kw.pop("chunk", None)
         Y = elm_predict_pallas(
             X, W, b, beta, activation=activation,
-            interpret=not _on_tpu(), **kw,
+            interpret=not on_tpu(), **pallas_kwargs(kw),
         )
         return Y.astype(out_dtype)
     from repro.kernels.elm_predict_ref import elm_predict_scan
@@ -92,8 +71,7 @@ def fused_predict(
 
 
 def predict_map(
-    x, feature_map, beta, *, use_kernel: bool | None = None,
-    tuning="cached", **kw,
+    x, feature_map, beta, *, use_kernel: bool | None = None, **kw,
 ):
     """f(x) = h(x) @ beta for any FeatureMap, fused where fusable.
 
@@ -118,14 +96,14 @@ def predict_map(
         return jnp.matmul(feature_map(x), beta, precision="highest")
     Y = fused_predict(
         rows, W, b, beta, activation=activation, use_kernel=use_kernel,
-        tuning=tuning, **kw,
+        **kw,
     )
     return Y.reshape(*lead, beta.shape[-1])
 
 
 def fused_predict_stacked(
     X, W, b, betas, tenant_ids, *, activation: str = "sigmoid",
-    use_kernel: bool | None = None, tuning="cached", **kw,
+    use_kernel: bool | None = None, **kw,
 ):
     """Y[n] = g(X W + b)[n] @ betas[tenant_ids[n]] without
     materializing H: one launch for a batch mixing many tenants.
@@ -137,24 +115,12 @@ def fused_predict_stacked(
     from repro.kernels.elm_predict_ref import stacked_dtype
 
     out_dtype = stacked_dtype(X, W, betas)
-    use = (_on_tpu() or force_interpret()) if use_kernel is None else use_kernel
-    kw = autotune.resolve_config(
-        kw, tuning, op="stacked", impl="pallas" if use else "scan",
-        N=X.shape[0], D=X.shape[1], L=W.shape[1], M=betas.shape[2],
-        dtype=X.dtype, T=betas.shape[0],
-    )
-    if use:
+    if wants_kernel(use_kernel):
         from repro.kernels.elm_predict import elm_predict_stacked_pallas
 
-        if kw.get("chunk") is not None:
-            raise ValueError(
-                "chunk is the scan-fallback knob; the Pallas kernel "
-                "takes block_n/block_l"
-            )
-        kw.pop("chunk", None)
         Y = elm_predict_stacked_pallas(
             X, W, b, betas, tenant_ids, activation=activation,
-            interpret=not _on_tpu(), **kw,
+            interpret=not on_tpu(), **pallas_kwargs(kw),
         )
         return Y.astype(out_dtype)
     from repro.kernels.elm_predict_ref import elm_predict_stacked_scan
@@ -167,7 +133,7 @@ def fused_predict_stacked(
 
 def predict_stacked(
     x, feature_map, betas, tenant_ids, *,
-    use_kernel: bool | None = None, tuning="cached", **kw,
+    use_kernel: bool | None = None, **kw,
 ):
     """f_t(x) = h(x) @ betas[t] per row, fused where fusable.
 
@@ -199,5 +165,5 @@ def predict_stacked(
     W, b, activation = params
     return fused_predict_stacked(
         x, W, b, betas, ids, activation=activation,
-        use_kernel=use_kernel, tuning=tuning, **kw,
+        use_kernel=use_kernel, **kw,
     )

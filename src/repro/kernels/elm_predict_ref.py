@@ -3,8 +3,7 @@
 Four references:
 
 * ``predict_reference`` — the semantic oracle: materialize H, then
-  H @ beta. What the fused kernel must match (and the "unfused" subject
-  of benchmarks/serving_bench.py).
+  H @ beta. What the fused kernel must match.
 * ``elm_predict_scan`` — the *streaming* jnp implementation: lax.scan
   over (chunk, D) row tiles, each producing its (chunk, M) output
   slice, so peak memory is the chunk working set, not the (N, L)

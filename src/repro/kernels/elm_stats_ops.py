@@ -1,6 +1,6 @@
 """Dispatching wrapper for the fused feature->moment kernel.
 
-Backend policy (mirrors gram_ops):
+Backend policy (``kernels/dispatch.py``):
   * TPU              -> the Pallas kernel (H never touches HBM)
   * use_kernel=True elsewhere -> the kernel in interpret mode
     (correctness path for tests; slow)
@@ -18,94 +18,32 @@ Block-knob mapping (Pallas grid -> scan fallback):
     the scan computes all L hidden columns per chunk in one matmul.
     Passing a non-None ``block_l`` to the scan path raises instead of
     being silently dropped.
-
-Tuning policy (kernels/autotune.py): ``tuning="cached"`` (default)
-consults the measured-winner cache (TUNED_kernels.json) for this
-problem point and backend — explicit block kwargs always win, and a
-cache miss keeps the hard-coded defaults, so cold-start behavior is
-unchanged. ``tuning="off"`` never consults; ``tuning={...}`` applies
-an explicit config dict.
 """
 
 from __future__ import annotations
 
-import os
-
-import jax
-
-from repro.kernels import autotune
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-def force_interpret() -> bool:
-    """True when CI's pallas-interpret leg forces the kernel paths.
-
-    ``REPRO_FORCE_INTERPRET=1`` makes every kernel dispatcher whose
-    caller left ``use_kernel=None`` take its Pallas branch (interpret
-    mode off-TPU), so the kernel code paths are exercised on CPU
-    runners instead of only the scan/oracle fallbacks. An explicit
-    ``use_kernel=`` from the caller always wins.
-    """
-    return os.environ.get("REPRO_FORCE_INTERPRET", "").strip() not in ("", "0")
-
-
-def scan_kwargs(kw: dict) -> dict:
-    """Map Pallas block kwargs onto the scan fallback's ``chunk``.
-
-    block_n -> chunk (same streaming role); block_l has no scan
-    meaning and raises; both block_n and chunk is a conflict.
-    """
-    kw = dict(kw)
-    if kw.get("block_l") is not None:
-        raise ValueError(
-            "block_l is a Pallas grid knob with no scan-fallback "
-            "equivalent (the scan computes all L hidden columns per "
-            "chunk); pass chunk= (or block_n=, which maps to chunk) "
-            "instead, or drop block_l"
-        )
-    kw.pop("block_l", None)
-    block_n = kw.pop("block_n", None)
-    if block_n is not None:
-        if kw.get("chunk") is not None:
-            raise ValueError(
-                f"both block_n={block_n} and chunk={kw['chunk']} were "
-                "passed to the scan fallback; block_n maps to chunk — "
-                "pass exactly one"
-            )
-        kw["chunk"] = block_n
-    return kw
+from repro.kernels.dispatch import (
+    on_tpu,
+    pallas_kwargs,
+    scan_kwargs,
+    wants_kernel,
+)
 
 
 def fused_moments(
     X, W, b, T, *, activation: str = "sigmoid",
-    use_kernel: bool | None = None, tuning="cached", **kw,
+    use_kernel: bool | None = None, **kw,
 ):
     """(P, Q) f32 from raw inputs without materializing H.
 
-    For activation="rbf" pass W = centers^T and b = gamma. ``tuning``
-    selects the block-knob policy (see module docstring).
+    For activation="rbf" pass W = centers^T and b = gamma.
     """
-    use = (_on_tpu() or force_interpret()) if use_kernel is None else use_kernel
-    kw = autotune.resolve_config(
-        kw, tuning, op="stats", impl="pallas" if use else "scan",
-        N=X.shape[0], D=X.shape[1], L=W.shape[1], M=T.shape[1],
-        dtype=X.dtype,
-    )
-    if use:
+    if wants_kernel(use_kernel):
         from repro.kernels.elm_stats import elm_stats_pallas
 
-        if kw.get("chunk") is not None:
-            raise ValueError(
-                "chunk is the scan-fallback knob; the Pallas kernel "
-                "takes block_n/block_l"
-            )
-        kw.pop("chunk", None)
         return elm_stats_pallas(
             X, W, b, T, activation=activation,
-            interpret=not _on_tpu(), **kw,
+            interpret=not on_tpu(), **pallas_kwargs(kw),
         )
     from repro.kernels.elm_stats_ref import elm_stats_scan
 
@@ -114,14 +52,14 @@ def fused_moments(
 
 def fused_preact_moments(
     Z, b, T, *, activation: str = "sigmoid",
-    use_kernel: bool | None = None, tuning="cached", **kw,
+    use_kernel: bool | None = None, **kw,
 ):
     """(P, Q) f32 from an assembled preactivation without materializing H.
 
     The vertical-mode entry: Z = sum_i X_i W_i was already reduced
     across column-sliced nodes (core/vertical.py), so the kernel only
     applies bias + activation per tile before the moment accumulation.
-    Same backend/tuning policy as ``fused_moments``; "rbf" is rejected
+    Same backend policy as ``fused_moments``; "rbf" is rejected
     (no additive preactivation form).
     """
     if activation == "rbf":
@@ -129,24 +67,12 @@ def fused_preact_moments(
             "rbf has no preactivation form; vertical mode supports "
             "RandomFeatureMap activations only"
         )
-    use = (_on_tpu() or force_interpret()) if use_kernel is None else use_kernel
-    kw = autotune.resolve_config(
-        kw, tuning, op="preact_stats", impl="pallas" if use else "scan",
-        N=Z.shape[0], D=0, L=Z.shape[1], M=T.shape[1],
-        dtype=Z.dtype,
-    )
-    if use:
+    if wants_kernel(use_kernel):
         from repro.kernels.elm_stats import elm_preact_stats_pallas
 
-        if kw.get("chunk") is not None:
-            raise ValueError(
-                "chunk is the scan-fallback knob; the Pallas kernel "
-                "takes block_n/block_l"
-            )
-        kw.pop("chunk", None)
         return elm_preact_stats_pallas(
             Z, b, T, activation=activation,
-            interpret=not _on_tpu(), **kw,
+            interpret=not on_tpu(), **pallas_kwargs(kw),
         )
     from repro.kernels.elm_stats_ref import preact_stats_scan
 

@@ -2,31 +2,23 @@
 
 from __future__ import annotations
 
-import jax
-
-from repro.kernels.elm_stats_ops import force_interpret
+from repro.kernels.dispatch import on_tpu, wants_kernel
 from repro.kernels.gram_ref import cross_reference, gram_reference
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def gram(H, *, use_kernel: bool | None = None, **kw):
-    use = (_on_tpu() or force_interpret()) if use_kernel is None else use_kernel
-    if use:
+    if wants_kernel(use_kernel):
         from repro.kernels.gram import gram_pallas
 
-        return gram_pallas(H, interpret=not _on_tpu(), **kw)
+        return gram_pallas(H, interpret=not on_tpu(), **kw)
     return gram_reference(H)
 
 
 def cross(H, T, *, use_kernel: bool | None = None, **kw):
-    use = (_on_tpu() or force_interpret()) if use_kernel is None else use_kernel
-    if use:
+    if wants_kernel(use_kernel):
         from repro.kernels.gram import cross_pallas
 
-        return cross_pallas(H, T, interpret=not _on_tpu(), **kw)
+        return cross_pallas(H, T, interpret=not on_tpu(), **kw)
     return cross_reference(H, T)
 
 

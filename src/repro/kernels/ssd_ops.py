@@ -8,13 +8,8 @@ branches.
 
 from __future__ import annotations
 
-import jax
-
+from repro.kernels.dispatch import on_tpu
 from repro.kernels.ssd_ref import ssd_reference
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def ssd(
@@ -28,7 +23,7 @@ def ssd(
     initial_state=None,
     use_kernel: bool | None = None,
 ):
-    use = _on_tpu() if use_kernel is None else use_kernel
+    use = on_tpu() if use_kernel is None else use_kernel
     if use:
         from repro.kernels.ssd_scan import ssd_pallas
 
@@ -40,6 +35,6 @@ def ssd(
             C,
             chunk=chunk,
             initial_state=initial_state,
-            interpret=not _on_tpu(),
+            interpret=not on_tpu(),
         )
     return ssd_reference(x, dt, A, B, C, chunk=chunk, initial_state=initial_state)

@@ -1,5 +1,6 @@
 """The VMEM a Pallas kernel may request, shared by the kernels that size
-their blocks or choose their grid from it (elm_gossip, elm_stats)."""
+their blocks or choose their grid from it (elm_gossip, elm_stats) and
+by the gossip dispatcher's choice of arm."""
 
 from __future__ import annotations
 
@@ -9,6 +10,11 @@ from jax.experimental.pallas import tpu as pltpu
 #: the default device is no TPU (source: the "TPU v5 lite" entry of
 #: ``jax.experimental.pallas.tpu.get_tpu_info``, JAX 0.9)
 _V5E_VMEM_BYTES = 128 * 2**20
+
+#: the scoped VMEM limit a Mosaic kernel runs under unless its compiler
+#: params raise it: ``vmem_params`` raises it only past this, and the
+#: gossip dispatcher keeps its multi-round arm to what fits in it
+DEFAULT_SCOPED_VMEM = 16 * 2**20
 
 
 def vmem_budget() -> int:
@@ -26,10 +32,10 @@ def vmem_budget() -> int:
 
 def vmem_params(nbytes: int):
     """Raise the scoped VMEM limit to the resident set plus a 4 MiB
-    margin when that passes the 16 MiB default. The default is only a
-    starting point: resident sets are held to ``vmem_budget()``, the
-    most this may ask for."""
+    margin when that passes ``DEFAULT_SCOPED_VMEM``. The default is
+    only a starting point: resident sets are held to ``vmem_budget()``,
+    the most this may ask for."""
     limit = nbytes + 4 * 2**20
-    if limit <= 16 * 2**20:
+    if limit <= DEFAULT_SCOPED_VMEM:
         return None
     return pltpu.CompilerParams(vmem_limit_bytes=int(limit))

@@ -33,8 +33,7 @@ Hot-swap protocol (bounded staleness):
    that produced it, and the serve-time guarantee is
    ``store.version_at_flush - response.version <= max_staleness``.
 3. ``freeze()`` pins the current snapshot (publishes keep landing in
-   the store but are not picked up) — the ablation arm of
-   ``benchmarks/serving_bench.py``; ``thaw()`` resumes hot-swapping.
+   the store but are not picked up); ``thaw()`` resumes hot-swapping.
 
 * ``ContinuousELMServer`` — the continuous-batching mode (the idiom of
   ``examples/continuous_batching.py``, adapted to row-parallel ELM
@@ -54,8 +53,8 @@ Hot-swap protocol (bounded staleness):
 Both servers share the int8-beta serving arm: ``beta_mode="int8"``
 round-trips each served beta through the compression plane's per-tile
 stochastic int8 quantizer (core/compression.int8_roundtrip, keyed
-deterministically by snapshot version and node) — the bytes/latency
-tradeoff row of benchmarks/serving_bench.py.
+deterministically by snapshot version and node) — fewer beta bytes
+for a bounded accuracy cost.
 
 Multi-tenant mode: constructing either server over a
 ``serving.TenantRegistry`` (instead of a ``BetaStore``) switches it to

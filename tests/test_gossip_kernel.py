@@ -5,7 +5,7 @@ Pallas-interpret parity against the dense DenseMixer round (plain,
 chunked, bf16, explicit-payload, time-varying, fault-masked), the
 in-kernel multi-round arm, engine-level NeighborMixer composition
 (FaultyMixer / CompressedMixer / membership churn), int8 bitwise
-determinism, the dense-fallback heuristic, and op="gossip" autotuning.
+determinism, and the dense-fallback heuristic.
 """
 
 import jax
@@ -22,7 +22,7 @@ from repro.core.consensus import (
     random_geometric,
 )
 from repro.core.mixers import DenseMixer, NeighborMixer
-from repro.kernels import autotune, elm_gossip_ops
+from repro.kernels import elm_gossip_ops
 from repro.kernels import elm_gossip_ref as ref
 from repro.kernels.elm_gossip import (
     elm_gossip_pallas,
@@ -31,7 +31,7 @@ from repro.kernels.elm_gossip import (
     multiround_vmem_bytes,
     round_vmem_bytes,
 )
-from repro.kernels.vmem import vmem_budget
+from repro.kernels.vmem import DEFAULT_SCOPED_VMEM, vmem_budget
 
 TOL = dict(rtol=2e-5, atol=2e-5)
 
@@ -233,7 +233,7 @@ def test_block_fit_at_rgg1024(payload):
     budget the kernel may request fits blocks of 8 within v5e's 128 MiB
     VMEM, margin included."""
     V, L, M = 1024, 256, 10
-    assert fit_block_v(V, L, M, 8, 16 * 2**20, payload=payload) == 1
+    assert fit_block_v(V, L, M, 8, DEFAULT_SCOPED_VMEM, payload=payload) == 1
     bv = fit_block_v(V, L, M, 8, vmem_budget(), payload=payload)
     assert bv == 8
     need = round_vmem_bytes(V, L, M, bv, payload=payload) + 4 * 2**20
@@ -248,7 +248,7 @@ def test_pallas_multiround_arm_matches_scan(compress):
     betas, omegas = _state(8, 16, 3, seed=4)
     idx, w, deg = ref.neighbor_lists(adj)
     assert multiround_vmem_bytes(8, 16, 3, 2, int(idx.shape[-1])) < (
-        autotune.VMEM_BUDGET
+        DEFAULT_SCOPED_VMEM
     )
     want = ref.elm_gossip_scan(
         betas, omegas, idx, w, deg, 0.2, num_rounds=5, compress=compress
@@ -318,7 +318,7 @@ def test_dispatcher_arms_agree():
 
 
 def test_prefers_dense_pins():
-    # the BENCH_consensus grid's arm choices (DESIGN.md §15), pinned
+    # the consensus grid's measured arm choices (DESIGN.md §15), pinned
     # at each backend's slack: TPU trusts the roofline ratio almost
     # directly; off-TPU the dense GEMM's efficiency edge means only
     # large V / small L points hand the round to the gather arm
@@ -497,70 +497,6 @@ def test_compress_payload_rejects_unknown_mode():
 
     with pytest.raises(ValueError, match="CompressionSpec"):
         compress_payload(jnp.ones((2, 2)), "int8")
-
-
-# ---------------------------------------------------------------------------
-# Autotune op="gossip"
-# ---------------------------------------------------------------------------
-
-
-def _gossip_point(**kw):
-    base = dict(
-        op="gossip", impl="scan", N=16, D=4, L=16, M=3,
-        dtype="float32", backend=jax.default_backend(),
-    )
-    base.update(kw)
-    return autotune.TunePoint(**base)
-
-
-def test_gossip_candidates_clamped_and_include_default():
-    pt = _gossip_point(D=6)
-    cands = autotune.candidates(pt)
-    assert {"chunk": 6} in cands  # clamped to d_max
-    assert all(c["chunk"] <= 6 for c in cands)
-    ptp = _gossip_point(impl="pallas", N=12)
-    candsp = autotune.candidates(ptp)
-    assert {"block_n": 8} in candsp  # the hard-coded default
-    assert all(c["block_n"] <= 12 for c in candsp)
-
-
-def test_gossip_roofline_prune_keeps_a_candidate():
-    pt = _gossip_point(N=64, D=6, L=128, M=8)
-    kept, _ = autotune.roofline_prune(pt, autotune.candidates(pt))
-    assert kept
-    est = autotune.estimate(pt, kept[0])
-    assert est["t_estimate"] > 0
-
-
-def test_gossip_tune_and_lookup_roundtrip(tmp_path):
-    path = str(tmp_path / "tuned.json")
-    cfg = autotune.tune(
-        "gossip", 16, 4, 16, 3, "float32", impl="scan",
-        cache_path=path, repeats=1,
-    )
-    assert 1 <= cfg["chunk"] <= 4
-    hit = autotune.lookup(
-        "gossip", 16, 4, 16, 3, "float32", impl="scan", cache_path=path
-    )
-    assert hit == cfg
-    # nearest-N fallback within the 4x window
-    near = autotune.lookup(
-        "gossip", 32, 4, 16, 3, "float32", impl="scan", cache_path=path
-    )
-    assert near == cfg
-
-
-def test_gossip_resolve_config_explicit_wins(tmp_path):
-    path = str(tmp_path / "tuned.json")
-    autotune.tune(
-        "gossip", 16, 4, 16, 3, "float32", impl="scan",
-        cache_path=path, repeats=1,
-    )
-    merged = autotune.resolve_config(
-        {"chunk": 2}, "cached", op="gossip", impl="scan",
-        N=16, D=4, L=16, M=3, dtype="float32", cache_path=path,
-    )
-    assert merged["chunk"] == 2
 
 
 # ---------------------------------------------------------------------------

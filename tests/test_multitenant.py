@@ -11,7 +11,6 @@ import pytest
 
 from repro.core import engine as engine_mod
 from repro.core.features import make_random_features
-from repro.kernels import autotune, elm_predict_ops
 from repro.kernels.elm_predict import elm_predict_stacked_pallas
 from repro.kernels.elm_predict_ops import (
     fused_predict_stacked,
@@ -134,7 +133,7 @@ def test_stacked_dispatcher_and_empty_batch():
     ref = predict_stacked_reference(X, W, b, betas, tids)
     for use_kernel in (False, True):
         out = fused_predict_stacked(
-            X, W, b, betas, tids, use_kernel=use_kernel, tuning="off"
+            X, W, b, betas, tids, use_kernel=use_kernel
         )
         assert _relerr(out, ref) < 2e-5
     fmap = make_random_features(jax.random.key(3), 4, 24)
@@ -712,59 +711,14 @@ def test_continuous_rejects_retired_at_refresh():
     assert isinstance(err, RetiredTenantError)
 
 
-# ---------------------------------------------------------------------------
-# Autotuner: the stacked op
-# ---------------------------------------------------------------------------
-
-
-@pytest.fixture()
-def _fresh_memo():
-    autotune.clear_memo()
-    yield
-    autotune.clear_memo()
-
-
-def test_stacked_tunepoint_key_carries_T(_fresh_memo):
-    pt = autotune.TunePoint(
-        op="stacked", impl="scan", N=1024, D=8, L=64, M=4,
-        dtype="float32", backend="cpu", T=16,
-    )
-    assert "_T16" in pt.key
-    # T=0 (the single-beta ops) keeps the committed key format stable
-    pt0 = autotune.TunePoint(
-        op="predict", impl="scan", N=1024, D=8, L=64, M=4,
-        dtype="float32", backend="cpu",
-    )
-    assert "_T" not in pt0.key
-    with pytest.raises(ValueError, match="T"):
-        autotune.TunePoint(
-            op="stacked", impl="scan", N=1024, D=8, L=64, M=4,
-            dtype="float32", backend="cpu",
-        )
-
-
-def test_stacked_candidates_and_tune_roundtrip(tmp_path, _fresh_memo):
-    path = str(tmp_path / "tuned.json")
-    cfg = autotune.tune(
-        "stacked", 64, 4, 16, 2, "float32", impl="scan", T=3,
-        cache_path=path, repeats=1,
-    )
-    assert "chunk" in cfg
-    hit = autotune.lookup(
-        "stacked", 64, 4, 16, 2, "float32", impl="scan", T=3,
-        cache_path=path,
-    )
-    assert hit == cfg
-
-
 def test_stacked_dispatcher_consults_tuning_dict():
     X, W, b, betas, tids = _stacked_problem(32, 4, 16, 2, 3)
     ref = predict_stacked_reference(X, W, b, betas, tids)
     out = fused_predict_stacked(
-        X, W, b, betas, tids, use_kernel=False, tuning={"chunk": 8}
+        X, W, b, betas, tids, use_kernel=False, chunk=8
     )
     assert _relerr(out, ref) < 2e-5
     with pytest.raises(ValueError, match="chunk is the scan-fallback"):
         fused_predict_stacked(
-            X, W, b, betas, tids, use_kernel=True, tuning={"chunk": 8}
+            X, W, b, betas, tids, use_kernel=True, chunk=8
         )

@@ -398,15 +398,6 @@ def test_preact_dispatch_and_rbf_rejection():
         elm_stats_ops.fused_preact_moments(Z, b, T, activation="rbf")
 
 
-def test_force_interpret_env_flag(monkeypatch):
-    monkeypatch.setenv("REPRO_FORCE_INTERPRET", "1")
-    assert elm_stats_ops.force_interpret()
-    monkeypatch.setenv("REPRO_FORCE_INTERPRET", "0")
-    assert not elm_stats_ops.force_interpret()
-    monkeypatch.delenv("REPRO_FORCE_INTERPRET")
-    assert not elm_stats_ops.force_interpret()
-
-
 # ---------------------------------------------------------------------------
 # Serving a vertically assembled model
 # ---------------------------------------------------------------------------
